@@ -492,7 +492,7 @@ _SHARDED_SCRIPT = """
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro.compat import AxisType, make_compat_mesh, set_mesh
+    from jax.sharding import AxisType
     from repro.configs import get_smoke_config
     from repro.core import learn_topology
     from repro.core.mixing import (BirkhoffSchedule, PermPool, PoolSwap,
@@ -515,7 +515,7 @@ _SHARDED_SCRIPT = """
     d_max = int(max((np.abs(W[i]) > 1e-9).sum() - (W[i, i] > 1e-9)
                     for i in range(n)))
 
-    mesh = make_compat_mesh((n, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((n, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     cfg = get_smoke_config("qwen3-0.6b")
     mk = lambda tr, pl, comp=None: make_train_setup(
         cfg, mesh, mode="dsgd", online_w=True, sharded_transport=tr,
@@ -528,7 +528,7 @@ _SHARDED_SCRIPT = """
            "pool_bytes_per_step": s_pool.comm_bytes_per_step,
            "allgather_bytes_per_step": s_ag.comm_bytes_per_step}
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(s_pool.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
         toks = jax.random.randint(jax.random.PRNGKey(1), (steps, n, 1, 32), 0,
                                   cfg.vocab_size)

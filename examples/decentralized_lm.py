@@ -1,18 +1,18 @@
 """End-to-end driver: decentralized LM pretraining with a learned topology.
 
-Runs D-SGD over a (data x model) device mesh on a reduced transformer for a
-few hundred steps with domain-skewed synthetic data -- the systems-scale
-version of the paper's experiments. On the CPU container this uses 8 forced
-host devices; the same code runs the full config on a TPU pod with --full.
+Runs D-SGD over a (data x model) mesh of the devices present on a reduced
+transformer for a few hundred steps with domain-skewed synthetic data --
+the systems-scale version of the paper's experiments. Each device is one
+D-SGD node; ``--full`` runs the published config (on a TPU).
 
     PYTHONPATH=src python examples/decentralized_lm.py --steps 200
+
+On a CPU, ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` in the
+environment gives eight host devices, hence eight nodes.
 """
 
 import os
 import sys
-
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

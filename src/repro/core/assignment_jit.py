@@ -21,7 +21,7 @@ algorithm into one XLA computation:
 * an optional forward-reverse variant (``variant="forward_reverse"``)
   that alternates row-bids with column-bids to shorten eviction chains
   on the near-duplicate-row instances label-skew Pi produces;
-* ``float64`` throughout via a ``jax.experimental.enable_x64`` scope
+* ``float64`` throughout via a ``jax.enable_x64(True)`` scope
   around trace and execution (the repo's global x64 default stays off),
   so the 1e-12-relative quantization grid is meaningful.
 
@@ -64,7 +64,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from .assignment import (
     AUCTION_REL_GRID,
@@ -687,7 +686,7 @@ def auction_assignment_jit(
         n, variant == "forward_reverse", validate, int(gs_threshold),
         int(max_iters),
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         if have_warm:
             warm_prices = jnp.asarray(warm.prices, jnp.float64)
             warm_scale = jnp.asarray(warm.pending_scale, jnp.float64)

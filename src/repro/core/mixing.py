@@ -1964,7 +1964,6 @@ def measure_sharded_transport(
     :func:`measure_transport` (:func:`_best_of_timed`), synthetic (n, p)
     f32 data, width capped at ``_MEASURE_MAX_ELEMENTS`` total elements.
     """
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec
 
     if mesh.shape[axis_name] != n_nodes:
@@ -1991,7 +1990,7 @@ def measure_sharded_transport(
 
     def sharded(fn):
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
                 axis_names={axis_name}, check_vma=False,
             )
@@ -2065,8 +2064,8 @@ def mix_dense(params_stack: PyTree, W: jax.Array, use_kernel: bool = False) -> P
       params_stack: pytree whose leaves have shape (n, ...).
       W: (n, n) mixing matrix.
       use_kernel: route 2D-flattened leaves through the Pallas gossip_mix
-        kernel (interpret mode auto-selected on non-TPU backends) instead
-        of einsum.
+        kernel (interpreted on the ``cpu`` backend only, by
+        ``repro.kernels.default_interpret``) instead of einsum.
     """
     if use_kernel:
         from repro.kernels.gossip_mix import ops as gossip_ops
@@ -2134,8 +2133,8 @@ def mix_schedule_stacked(
         the per-leaf gathers with zero copies, whereas flattening pays the
         concat/split passes every step.
       use_kernel: route the flat buffer through the Pallas
-        ``gossip_schedule`` kernel (implies single_buffer; interpret mode
-        auto-selected on non-TPU backends).
+        ``gossip_schedule`` kernel (implies single_buffer; interpreted on
+        the ``cpu`` backend only, by ``repro.kernels.default_interpret``).
       block_p: pad the flat buffer to a multiple of this at flatten time
         (defaults to the kernel's tile width when ``use_kernel``).
     """

@@ -43,7 +43,7 @@ from repro.core.mixing import (
 )
 from repro.obs.trace import Tracer
 from repro.train.checkpoints import latest_step, restore_checkpoint, save_checkpoint
-from repro.train.metrics import CommMeter, mix_bytes_per_step
+from repro.train.metrics import CommMeter, mix_bytes_per_step, sq_error_series
 
 _NULL_TRACER = Tracer(enabled=False)
 
@@ -177,8 +177,7 @@ def run_faulty_mean_estimation(
             th = mix_schedule_arrays_stale(
                 buf, ScheduleArrays(gammas=g_t, perms=p_t), d_t
             )
-            err = jnp.square(th[:, 0] - theta_star)
-            return (th, buf), (jnp.mean(err), jnp.max(err), jnp.min(err))
+            return (th, buf), (jnp.square(th[:, 0] - theta_star),)
 
         return jax.lax.scan(step, carry, xs)
 
@@ -213,8 +212,7 @@ def run_faulty_mean_estimation(
             gdev = jnp.max(jnp.sum(jnp.square(grads - gbar), axis=1))
             gbar_sq = jnp.sum(jnp.square(gbar))
             return (th, buf), (
-                jnp.mean(err), jnp.max(err), jnp.min(err), err,
-                stats, cons, gdev, gbar_sq,
+                err, stats, cons, gdev, gbar_sq,
             )
 
         return jax.lax.scan(step, carry, xs)
@@ -262,7 +260,6 @@ def run_faulty_mean_estimation(
     meter = CommMeter(per_step_bytes=mix_bytes_per_step(
         "allgather", n_nodes=n, p_total=1,
     ))
-    mse_l, mx_l, mn_l = [], [], []
     nodes_l: list[np.ndarray] = []
     swaps: list[int] = []
     stopped_at = None
@@ -278,24 +275,18 @@ def run_faulty_mean_estimation(
         with tracer.span("sim.segment", t0=t0, k=k):
             if screened:
                 mult_k, xor_k = injector.corrupt_stream(t0, k)
-                carry, (e_mean, e_max, e_min, e_nodes, stats, cons, gdev,
-                        gbars) = roll(
+                carry, (e_nodes, stats, cons, gdev, gbars) = roll(
                     carry,
                     (zs[t0 : t0 + k], jnp.asarray(gammas_k),
                      jnp.asarray(perms_k), jnp.asarray(delays_k),
                      jnp.asarray(mult_k), jnp.asarray(xor_k)),
                 )
             else:
-                carry, (e_mean, e_max, e_min) = roll(
+                carry, (e_nodes,) = roll(
                     carry,
                     (zs[t0 : t0 + k], jnp.asarray(gammas_k),
                      jnp.asarray(perms_k), jnp.asarray(delays_k)),
                 )
-            jax.block_until_ready(e_mean)
-        mse_l.append(np.asarray(e_mean))
-        mx_l.append(np.asarray(e_max))
-        mn_l.append(np.asarray(e_min))
-        if screened:
             nodes_l.append(np.asarray(e_nodes))
         if staleness is not None:
             fates = [
@@ -350,11 +341,8 @@ def run_faulty_mean_estimation(
             stopped_at = t0
             break
 
-    empty = np.zeros((0,))
     return {
-        "mean_sq_error": np.concatenate(mse_l) if mse_l else empty,
-        "max_sq_error": np.concatenate(mx_l) if mx_l else empty,
-        "min_sq_error": np.concatenate(mn_l) if mn_l else empty,
+        **sq_error_series(nodes_l, n),
         "theta": np.asarray(theta),
         "n_traces": n_traces,
         "swaps": swaps,
@@ -367,5 +355,7 @@ def run_faulty_mean_estimation(
         # separates honest-node tail loss from the quarantined nodes'
         # solo-SGD error (the Byzantine-robust convention -- a liar's
         # own loss is not the defense's responsibility)
-        "sq_error_nodes": np.concatenate(nodes_l) if nodes_l else None,
+        "sq_error_nodes": (
+            np.concatenate(nodes_l) if screened and nodes_l else None
+        ),
     }

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels.interpret import resolve_interpret
+
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 128
@@ -116,7 +117,7 @@ def flash_attention_pallas(
     softcap: float = 0.0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: (B, S, H, D); k/v: (B, S, Hkv, D); S % block == 0, D MXU-aligned."""
     B, S, H, D = q.shape
@@ -165,9 +166,9 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)

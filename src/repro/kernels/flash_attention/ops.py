@@ -36,7 +36,7 @@ def flash_attention(
     softcap: float = 0.0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_ref: bool = False,
 ) -> jax.Array:
     """Flash attention with GQA. q: (B,S,H,D); k/v: (B,S,Hkv,D)."""

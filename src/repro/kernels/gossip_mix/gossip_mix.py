@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.interpret import resolve_interpret
+
 DEFAULT_BLOCK_P = 2048
 
 
@@ -39,7 +41,7 @@ def gossip_mix_pallas(
     W: jax.Array,
     *,
     block_p: int = DEFAULT_BLOCK_P,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``out = W @ theta`` with theta (n, P), P a multiple of ``block_p``."""
     n, P = theta.shape
@@ -55,5 +57,5 @@ def gossip_mix_pallas(
         ],
         out_specs=pl.BlockSpec((n, block_p), lambda p: (0, p)),
         out_shape=jax.ShapeDtypeStruct((n, P), theta.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(W, theta)

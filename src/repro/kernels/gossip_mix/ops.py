@@ -1,8 +1,7 @@
 """Jitted public wrappers for the gossip mixing kernels.
 
-Handles backend auto-detection (Pallas interpret mode on every non-TPU
-backend), padding
-of the parameter axis to the kernel tile width, and the dense-vs-schedule
+Handles padding of the parameter axis to the kernel tile width, and the
+dense-vs-schedule
 dispatch: the dense matmul kernel is the right tool at ``L ~ n`` (an
 unstructured W has up to n atoms), the schedule kernel at ``L << n``
 (learned sparse topologies). ``gossip_apply`` picks automatically via the
@@ -20,21 +19,7 @@ from .gossip_mix import DEFAULT_BLOCK_P, gossip_mix_pallas
 from .gossip_schedule import gossip_schedule_pallas
 from .ref import gossip_mix_ref, gossip_schedule_ref
 
-__all__ = ["default_interpret", "gossip_mix", "gossip_schedule", "gossip_apply"]
-
-
-def default_interpret() -> bool:
-    """Interpret mode everywhere except real TPU.
-
-    These kernels use TPU-specific pallas features (PrefetchScalarGridSpec,
-    VMEM scratch) that only lower on the TPU backend, so GPU installs also
-    fall back to the interpreter rather than a failing Triton lowering.
-    """
-    return jax.default_backend() != "tpu"
-
-
-def _resolve_interpret(interpret: bool | None) -> bool:
-    return default_interpret() if interpret is None else interpret
+__all__ = ["gossip_mix", "gossip_schedule", "gossip_apply"]
 
 
 @functools.partial(
@@ -68,11 +53,10 @@ def gossip_mix(
 
     Pads the parameter axis to a multiple of ``block_p`` (the kernel's VMEM
     tile width), dispatches to the Pallas kernel, and strips the padding.
-    ``interpret=None`` auto-selects interpret mode on non-TPU backends
-    (see ``default_interpret``: the kernels only lower on TPU).
+    ``interpret=None`` resolves by ``repro.kernels.default_interpret``.
     ``use_ref=True`` routes to the pure-jnp oracle (for A/B testing).
     """
-    return _gossip_mix_impl(theta, W, block_p, _resolve_interpret(interpret), use_ref)
+    return _gossip_mix_impl(theta, W, block_p, interpret, use_ref)
 
 
 @functools.partial(
@@ -115,13 +99,12 @@ def gossip_schedule(
     ``pre_padded=True`` asserts the caller already padded P to a multiple of
     ``block_p`` (the single-buffer path pads once at flatten time via
     ``ravel_stack``) and skips the per-call pad/strip entirely.
-    ``interpret=None`` auto-selects interpret mode on non-TPU backends
-    (see ``default_interpret``: the kernels only lower on TPU).
+    ``interpret=None`` resolves by ``repro.kernels.default_interpret``.
     """
     coeffs = jnp.asarray(coeffs, jnp.float32)
     perms = jnp.asarray(perms, jnp.int32)
     return _gossip_schedule_impl(
-        theta, coeffs, perms, block_p, _resolve_interpret(interpret), use_ref, pre_padded
+        theta, coeffs, perms, block_p, interpret, use_ref, pre_padded
     )
 
 

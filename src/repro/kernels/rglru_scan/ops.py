@@ -20,7 +20,7 @@ def rglru_scan(
     *,
     block_s: int = DEFAULT_BLOCK_S,
     block_d: int = DEFAULT_BLOCK_D,
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_ref: bool = False,
 ) -> jax.Array:
     """Linear recurrence h_t = a_t h_{t-1} + b_t over axis 1 of (B, S, D).

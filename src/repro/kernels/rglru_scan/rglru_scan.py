@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels.interpret import resolve_interpret
+
 
 DEFAULT_BLOCK_S = 256
 DEFAULT_BLOCK_D = 512
@@ -59,7 +60,7 @@ def rglru_scan_pallas(
     *,
     block_s: int = DEFAULT_BLOCK_S,
     block_d: int = DEFAULT_BLOCK_D,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """a, b: (B, S, D); S % block_s == 0, D % block_d == 0."""
     B, S, D = a.shape
@@ -80,8 +81,8 @@ def rglru_scan_pallas(
         out_specs=pl.BlockSpec((1, block_s, block_d), idx),
         out_shape=jax.ShapeDtypeStruct((B, S, D), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -41,7 +41,6 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.serve.engine import make_serve_setup, prefill as engine_prefill
 from repro.train.lm_trainer import make_train_setup
-from repro.compat import set_mesh
 
 SKIPS: dict[tuple[str, str], str] = {
     ("whisper-small", "decode_32k"): "enc-dec ASR: decoder max target len 448",
@@ -223,7 +222,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str) -> dict:
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape["kind"] == "train":
                 cfg, lowered, meta = build_train_lowering(arch, shape, mesh, multi_pod)
             elif shape["kind"] == "prefill":

@@ -1,7 +1,10 @@
-"""Production device meshes (TPU v5e).
+"""Device meshes (TPU v5e).
 
-``make_production_mesh`` is a FUNCTION (never a module-level constant) so
+The mesh factories are FUNCTIONS (never module-level constants) so
 importing this module never touches jax device state.
+
+``make_device_mesh`` lays ``(data, model)`` over the devices present: the
+launcher's mesh on one chip, four chips, or forced host devices.
 
 Single-pod: (data=16, model=16) = 256 chips.
 Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the ``pod`` axis carries
@@ -12,9 +15,9 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType, make_compat_mesh
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "V5E"]
+__all__ = ["make_production_mesh", "make_device_mesh", "V5E"]
 
 
 # TPU v5e hardware constants used by the roofline analysis.
@@ -31,11 +34,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """The deployment mesh: 16x16 single pod or 2x16x16 across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_host_mesh(data: int = 4, model: int = 2):
-    """Small mesh for tests on forced host devices."""
-    return make_compat_mesh(
+def make_device_mesh(data: int, model: int):
+    """A ``(data, model)`` mesh over the ``data * model`` devices present."""
+    return jax.make_mesh(
         (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )

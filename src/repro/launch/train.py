@@ -1,26 +1,26 @@
 """End-to-end distributed training driver.
 
-Trains any assigned architecture with D-SGD over a device mesh:
+Trains any assigned architecture with D-SGD over the devices present:
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch qwen3-0.6b --steps 50 --topology stl-fw --budget 3
 
-On this CPU container it runs a reduced (smoke) config on a small forced
-host-device mesh; on a real TPU slice the same flags with ``--full`` and the
-production mesh run the full configuration. The learned STL-FW topology is
-built from the data pipeline's per-node domain histograms -- exactly the
-paper's pre-processing step -- and executed as a Birkhoff ppermute schedule.
+The mesh is ``(data, model)`` over ``jax.devices()``; by default every
+device is one D-SGD node (``--data`` = device count, ``--model`` = 1).
+Without ``--full`` the architecture's reduced smoke config runs; with it,
+the published config. The learned STL-FW topology is built from the data
+pipeline's per-node domain histograms -- exactly the paper's
+pre-processing step -- and executed as a Birkhoff ppermute schedule.
+
+``run(argv)`` is the same entry point in-process: it returns the per-step
+losses, the setup, the final parameters, the mesh and the schedule.
 """
 
-import os
-
-if "XLA_FLAGS" not in os.environ:
-    # host-device mesh for CPU runs; harmless on real TPU launches where the
-    # flag is managed by the launcher
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+from __future__ import annotations
 
 import argparse
 import time
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -31,11 +31,12 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import learn_topology, schedule_from_result, topology as topo
 from repro.core.mixing import schedule_from_matrix
 from repro.data.tokens import DomainSkewCorpus, TokenBatcher
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh
 from repro.train.checkpoints import CheckpointManager
 from repro.train.lm_trainer import make_train_setup
-from repro.train.metrics import MetricLogger
-from repro.compat import set_mesh
+
+PARAM_SEED = 0  # the initial parameters are init_params(PRNGKey(PARAM_SEED))
 
 
 def build_topology(kind: str, Pi: np.ndarray, budget: int, lam: float):
@@ -51,8 +52,8 @@ def build_topology(kind: str, Pi: np.ndarray, budget: int, lam: float):
     raise ValueError(kind)
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
+def _parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(prog="python -m repro.launch.train")
     ap.add_argument("--arch", default="qwen3-0.6b", choices=list(ARCH_IDS))
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=5e-3)
@@ -63,19 +64,58 @@ def main() -> None:
     ap.add_argument("--budget", type=int, default=2, help="STL-FW d_max")
     ap.add_argument("--lam", type=float, default=0.1)
     ap.add_argument("--full", action="store_true",
-                    help="full config on the production mesh (TPU)")
-    ap.add_argument("--data", type=int, default=4)
-    ap.add_argument("--model", type=int, default=2)
+                    help="the published config (default: the smoke config)")
+    ap.add_argument("--data", type=int, default=None,
+                    help="D-SGD nodes (default: device count / --model)")
+    ap.add_argument("--model", type=int, default=1,
+                    help="tensor-parallel devices per node")
     ap.add_argument("--ckpt-dir", default=None)
-    args = ap.parse_args()
+    return ap
 
-    if args.full:
-        mesh = make_production_mesh()
-        cfg = get_config(args.arch)
-    else:
-        mesh = make_host_mesh(args.data, args.model)
-        cfg = get_smoke_config(args.arch)
+
+def _batch(cfg, toks: np.ndarray, labels: np.ndarray) -> dict:
+    batch = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
+    b, per, _ = toks.shape
+    if cfg.arch_type == "vlm":
+        batch["image_embeds"] = jnp.zeros(
+            (b, per, cfg.vision.num_patches, cfg.d_model), jnp.dtype(cfg.dtype)
+        )
+    if cfg.arch_type == "audio":
+        batch["frames"] = jnp.zeros(
+            (b, per, cfg.encoder.num_frames, cfg.d_model), jnp.dtype(cfg.dtype)
+        )
+        batch["tokens"] = batch["tokens"][..., :448]
+        batch["labels"] = batch["labels"][..., :448]
+    return batch
+
+
+def run(argv: Sequence[str] | None = None) -> dict:
+    """Train as the command line ``argv`` says; return what the run made.
+
+    Keys: ``losses`` (one float per step), ``setup`` (the ``TrainSetup``),
+    ``params`` (final, stacked per node), ``mesh``, ``schedule`` (None for
+    the complete graph), ``cfg``, ``batch0`` (the step-0 batch), and the
+    host clock's ``compile_s`` (the step's compile), ``step_s`` and
+    ``batch_s`` (one entry per step).
+    """
+    ap = _parser()
+    args = ap.parse_args(argv)
+    devices = jax.devices()
+    print(f"platform {devices[0].platform}  device_kind {devices[0].device_kind}  "
+          f"count {len(devices)}", flush=True)
+    enable_compile_cache()
+
+    n_dev = len(devices)
+    data = args.data if args.data is not None else n_dev // args.model
+    if data < 1 or data * args.model != n_dev:
+        ap.error(f"--data {data} x --model {args.model} must equal the "
+                 f"{n_dev} devices present")
+    mesh = make_device_mesh(data, args.model)
+    cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     n_nodes = mesh.shape["data"]
+    print(f"{cfg.name}: {cfg.num_layers} layers, d_model {cfg.d_model}, "
+          f"vocab {cfg.vocab_size}; mesh data={data} model={args.model}",
+          flush=True)
 
     # Heterogeneous data: one skewed domain mixture per node.
     n_domains = max(4, n_nodes // 2)
@@ -95,40 +135,55 @@ def main() -> None:
         lambda s: NamedSharding(mesh, s), setup.param_specs,
         is_leaf=lambda x: isinstance(x, P),
     )
-    logger = MetricLogger()
     ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
 
-    with set_mesh(mesh):
+    losses: list[float] = []
+    step_s: list[float] = []
+    batch_s: list[float] = []
+    step_fn = batch0 = compile_s = None
+    with jax.set_mesh(mesh):
         params = jax.jit(setup.init_params, out_shardings=shardings)(
-            jax.random.PRNGKey(0)
+            jax.random.PRNGKey(PARAM_SEED)
         )
-        step_fn = jax.jit(setup.train_step)
-        t0 = time.time()
         for t in range(args.steps):
-            toks, labels = batcher.next_batch(t)
-            batch = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
-            if cfg.arch_type == "vlm":
-                b, per, s = toks.shape
-                batch["image_embeds"] = jnp.zeros(
-                    (b, per, cfg.vision.num_patches, cfg.d_model), jnp.dtype(cfg.dtype)
-                )
-            if cfg.arch_type == "audio":
-                b, per, s = toks.shape
-                batch["frames"] = jnp.zeros(
-                    (b, per, cfg.encoder.num_frames, cfg.d_model), jnp.dtype(cfg.dtype)
-                )
-                batch["tokens"] = batch["tokens"][..., :448]
-                batch["labels"] = batch["labels"][..., :448]
+            tic = time.perf_counter()
+            batch = _batch(cfg, *batcher.next_batch(t))
+            batch_s.append(time.perf_counter() - tic)
+            if step_fn is None:
+                batch0 = batch
+                tic = time.perf_counter()
+                step_fn = jax.jit(setup.train_step).lower(params, None, batch).compile()
+                compile_s = time.perf_counter() - tic
+                print(f"compiled the step in {compile_s:.1f}s", flush=True)
+            tic = time.perf_counter()
             params, _, loss = step_fn(params, None, batch)
-            logger.log(t, loss=float(loss))
+            losses.append(float(loss))  # waits for the step
+            step_s.append(time.perf_counter() - tic)
             if t % 5 == 0 or t == args.steps - 1:
-                print(f"step {t:4d}  loss {float(loss):.4f}  "
-                      f"({(time.time()-t0)/(t+1):.2f}s/step)")
+                print(f"step {t:4d}  loss {losses[-1]:.4f}  "
+                      f"({step_s[-1]:.2f}s step, {batch_s[-1]:.2f}s batch)",
+                      flush=True)
         if ckpt is not None:
             ckpt.save(args.steps, jax.device_get(params))
             print(f"checkpoint written to {args.ckpt_dir}")
-    losses = logger.column("loss")
-    print(f"loss: {losses[0]:.4f} -> {losses[-1]:.4f} over {args.steps} steps")
+    if losses:
+        print(f"loss: {losses[0]:.4f} -> {losses[-1]:.4f} over {args.steps} steps")
+    return {
+        "losses": losses,
+        "setup": setup,
+        "params": params,
+        "mesh": mesh,
+        "schedule": schedule,
+        "cfg": cfg,
+        "batch0": batch0,
+        "compile_s": compile_s,
+        "step_s": step_s,
+        "batch_s": batch_s,
+    }
+
+
+def main() -> None:
+    run()
 
 
 if __name__ == "__main__":

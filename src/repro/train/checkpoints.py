@@ -22,6 +22,7 @@ from typing import Any
 import jax
 import msgpack
 import numpy as np
+from jax.sharding import NamedSharding
 
 PyTree = Any
 
@@ -60,7 +61,11 @@ def save_checkpoint(directory: str, step: int, tree: PyTree, metadata: dict | No
 
 
 def restore_checkpoint(directory: str, step: int, like: PyTree) -> tuple[PyTree, dict]:
-    """Restore into the structure of ``like`` (shapes/dtypes validated)."""
+    """Restore into the structure of ``like`` (shapes/dtypes validated).
+
+    Leaves whose template is sharded over a mesh are placed with the
+    template's sharding; the others come back as numpy arrays.
+    """
     path = os.path.join(directory, f"step_{step:08d}")
     with open(os.path.join(path, _MANIFEST)) as f:
         manifest = json.load(f)
@@ -77,6 +82,11 @@ def restore_checkpoint(directory: str, step: int, like: PyTree) -> tuple[PyTree,
         t_shape = tuple(np.shape(tmpl))
         if t_shape != tuple(shape):
             raise ValueError(f"shape mismatch: checkpoint {shape} vs template {t_shape}")
+        if isinstance(getattr(tmpl, "sharding", None), NamedSharding):
+            # land on the template's mesh: a leaf restored to the host
+            # would reach a jitted step as a different input type than
+            # the live state it replaces, and retrace it
+            arr = jax.device_put(arr, tmpl.sharding)
         leaves.append(arr)
     return jax.tree_util.tree_unflatten(treedef, leaves), manifest["metadata"]
 

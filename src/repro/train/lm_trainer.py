@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.compression import (
     Compressor,
     ef_init,
@@ -627,7 +626,7 @@ def gossip_fn(
                 )
             return mix_ppermute(p, schedule, axis)
 
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(node_specs,),
@@ -1216,7 +1215,7 @@ def make_train_setup(
             if probes is not None
             else P()
         )
-        return shard_map(
+        return jax.shard_map(
             per_node,
             mesh=mesh,
             in_specs=in_specs,

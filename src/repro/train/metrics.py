@@ -24,6 +24,7 @@ __all__ = [
     "mix_bytes_per_step",
     "staleness_transfer_fracs",
     "CommMeter",
+    "sq_error_series",
 ]
 
 
@@ -348,3 +349,24 @@ class MetricLogger:
                     for k, v in row.items()
                 }
                 f.write(json.dumps(clean) + "\n")
+
+
+def sq_error_series(errs, n: int) -> dict:
+    """Per-step node mean / max / min of squared errors.
+
+    ``errs`` is a list of per-segment arrays, each ``(steps, n)`` or
+    ``(n,)``, in step order.
+
+    The rollouts return each node's error and the node reductions run
+    here, once, on the host: a mean reduced inside each compiled program
+    follows that program's own summation order, so a scan and a loop
+    rollout of the same trajectory could report different last bits.
+    """
+    errs = np.concatenate(
+        [np.reshape(e, (-1, n)) for e in errs] or [np.zeros((0, n))]
+    ).astype(np.float32)
+    return {
+        "mean_sq_error": errs.mean(axis=1),
+        "max_sq_error": errs.max(axis=1),
+        "min_sq_error": errs.min(axis=1),
+    }

@@ -53,6 +53,7 @@ from .metrics import (
     MetricLogger,
     consensus_distance,
     mix_bytes_per_step,
+    sq_error_series,
     staleness_transfer_fracs,
 )
 
@@ -346,8 +347,7 @@ def run_mean_estimation(
                     use_kernel=use_kernel, schedule=sched, transport=transport,
                 )
                 new_carry = (theta, st)
-            err = jnp.square(theta[:, 0] - theta_star)
-            outs = (jnp.mean(err), jnp.max(err), jnp.min(err))
+            outs = (jnp.square(theta[:, 0] - theta_star),)
             if probes is not None:
                 # pure value computations on the post-mix params / this
                 # step's grads -- extra scan outputs, zero retraces
@@ -375,27 +375,17 @@ def run_mean_estimation(
         def roll(theta, st, zs):
             return jax.lax.scan(step, (theta, st), zs)
 
-        (theta, state), (mse, mx, mn) = roll(theta, state, zs)
-        mse, mx, mn = np.asarray(mse), np.asarray(mx), np.asarray(mn)
+        (theta, state), (err_steps,) = roll(theta, state, zs)
+        errs = [np.asarray(err_steps)]
     else:
         step_j = jax.jit(step)
         carry = (theta, state)
-        mse_l, mx_l, mn_l = [], [], []
+        errs = []
         for t in range(steps):
-            carry, (e_mean, e_max, e_min) = step_j(carry, zs[t])
-            mse_l.append(e_mean)
-            mx_l.append(e_max)
-            mn_l.append(e_min)
+            carry, (err,) = step_j(carry, zs[t])
+            errs.append(np.asarray(err))
         theta, state = carry
-        mse = np.asarray(jnp.stack(mse_l)) if mse_l else np.zeros((0,))
-        mx = np.asarray(jnp.stack(mx_l)) if mx_l else np.zeros((0,))
-        mn = np.asarray(jnp.stack(mn_l)) if mn_l else np.zeros((0,))
-    return {
-        "mean_sq_error": mse,
-        "max_sq_error": mx,
-        "min_sq_error": mn,
-        "theta": np.asarray(theta),
-    }
+    return {**sq_error_series(errs, n), "theta": np.asarray(theta)}
 
 
 def _run_mean_estimation_online(
@@ -470,7 +460,7 @@ def _run_mean_estimation_online(
         carry = (theta, state, ef_init(theta), sched0)
     else:
         carry = (theta, state, sched0)
-    mse_l, mx_l, mn_l = [], [], []
+    errs_l = []
     probe_names = probes.names() if probes is not None else ()
     health_l: dict[str, list] = {nm: [] for nm in probe_names}
     swaps: list[int] = []
@@ -484,11 +474,8 @@ def _run_mean_estimation_online(
         with tracer.span("sim.segment", t0=t0, k=length):
             carry, traces = roll(carry, zs[t0 : t0 + length], ph)
             traces = jax.block_until_ready(traces)
-        e_mean, e_max, e_min = traces[:3]
-        mse_l.append(np.asarray(e_mean))
-        mx_l.append(np.asarray(e_max))
-        mn_l.append(np.asarray(e_min))
-        for nm, series in zip(probe_names, traces[3:]):
+        errs_l.append(np.asarray(traces[0]))
+        for nm, series in zip(probe_names, traces[1:]):
             health_l[nm].append(np.asarray(series))
         meter.tick(length)
         t0 += length
@@ -505,9 +492,7 @@ def _run_mean_estimation_online(
     theta = carry[0]
     empty = np.zeros((0,))
     out = {
-        "mean_sq_error": np.concatenate(mse_l) if mse_l else empty,
-        "max_sq_error": np.concatenate(mx_l) if mx_l else empty,
-        "min_sq_error": np.concatenate(mn_l) if mn_l else empty,
+        **sq_error_series(errs_l, theta.shape[0]),
         "theta": np.asarray(theta),
         "n_traces": n_traces,
         "swaps": swaps,
@@ -571,8 +556,7 @@ def _run_mean_estimation_stale(
                 buf = stale_push(buf, half)
                 th = mix_schedule_arrays_stale(buf, sa, d_t)
                 new_c = (th, buf)
-            err = jnp.square(th[:, 0] - theta_star)
-            return new_c, (jnp.mean(err), jnp.max(err), jnp.min(err))
+            return new_c, jnp.square(th[:, 0] - theta_star)
 
         return jax.lax.scan(step, carry, xs)
 
@@ -586,16 +570,14 @@ def _run_mean_estimation_stale(
         carry = (theta, buffer)
     base = sched0
     meter = _online_comm_meter(n, 1, compression=compressor)
-    mse_l, mx_l, mn_l = [], [], []
+    errs_l = []
     swaps: list[int] = []
     t0 = 0
     while t0 < steps:
         k = min(seg, steps - t0)
         g_k, p_k, d_k = straggler_stream(staleness, base, delays[t0 : t0 + k])
-        carry, (e_mean, e_max, e_min) = roll(carry, (zs[t0 : t0 + k], g_k, p_k, d_k))
-        mse_l.append(np.asarray(e_mean))
-        mx_l.append(np.asarray(e_max))
-        mn_l.append(np.asarray(e_min))
+        carry, errs = roll(carry, (zs[t0 : t0 + k], g_k, p_k, d_k))
+        errs_l.append(np.asarray(errs))
         delivered, deferred = _staleness_meter_fracs(
             delays[t0 : t0 + k], staleness
         )
@@ -606,11 +588,8 @@ def _run_mean_estimation_stale(
             if new_sa is not None:
                 base = new_sa
                 swaps.append(t0 - 1)
-    empty = np.zeros((0,))
     return {
-        "mean_sq_error": np.concatenate(mse_l) if mse_l else empty,
-        "max_sq_error": np.concatenate(mx_l) if mx_l else empty,
-        "min_sq_error": np.concatenate(mn_l) if mn_l else empty,
+        **sq_error_series(errs_l, n),
         "theta": np.asarray(carry[0]),
         "n_traces": n_traces,
         "swaps": swaps,

@@ -14,9 +14,13 @@ import pytest
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def run_with_devices(code: str, n_devices: int = 8, timeout: int = 480) -> str:
+def run_with_devices(
+    code: str, n_devices: int = 8, timeout: int = 480, xla_flags: str = ""
+) -> str:
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={n_devices} {xla_flags}"
+    )
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
@@ -32,11 +36,11 @@ def test_ppermute_gossip_equals_dense_mixing():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.core import topology as T
         from repro.core.mixing import schedule_from_matrix, mix_ppermute, mix_dense
 
-        mesh = make_compat_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         W = T.ring(8)
         sched = schedule_from_matrix(W)
         x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 16)), jnp.float32)
@@ -44,10 +48,11 @@ def test_ppermute_gossip_equals_dense_mixing():
         def gossip(v):
             def inner(p):
                 return mix_ppermute(p, sched, "data")
-            return shard_map(inner, mesh=mesh, in_specs=(P("data"),),
-                                 out_specs=P("data"), axis_names={"data"})(v)
+            return jax.shard_map(inner, mesh=mesh, in_specs=(P("data"),),
+                                 out_specs=P("data"), axis_names={"data"},
+                                 check_vma=False)(v)
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = np.asarray(jax.jit(gossip)(x))
         want = np.asarray(mix_dense(x, jnp.asarray(W, jnp.float32)))
         assert np.allclose(got, want, atol=1e-5), np.abs(got - want).max()
@@ -60,19 +65,19 @@ def test_sharded_dsgd_step_runs_and_learns():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import learn_topology, schedule_from_result
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         Pi = np.eye(2)[np.arange(4) % 2].astype(float)
         sched = schedule_from_result(learn_topology(Pi, budget=2, lam=0.5))
         setup = make_train_setup(cfg, mesh, mode="dsgd", schedule=sched, lr=2e-2)
         sh = jax.tree.map(lambda s: NamedSharding(mesh, s), setup.param_specs,
                           is_leaf=lambda x: isinstance(x, P))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(setup.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
             batch = {k: jnp.zeros((4, 2, 32), jnp.int32) for k in ("tokens", "labels")}
             step = jax.jit(setup.train_step)
@@ -92,20 +97,20 @@ def test_gossip_every_k_amortization():
     out = run_with_devices("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import topology as T
         from repro.core.mixing import schedule_from_matrix
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         sched = schedule_from_matrix(T.complete(4))
         setup = make_train_setup(cfg, mesh, mode="dsgd", schedule=sched,
                                  lr=1e-2, gossip_every=3)
         sh = jax.tree.map(lambda s: NamedSharding(mesh, s), setup.param_specs,
                           is_leaf=lambda x: isinstance(x, P))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(setup.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
             toks = jax.random.randint(jax.random.PRNGKey(1), (4, 2, 32), 0, cfg.vocab_size)
             batch = {"tokens": toks, "labels": toks}
@@ -131,13 +136,13 @@ def test_multi_step_scan_bitwise_equals_loop():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import topology as T
         from repro.core.mixing import schedule_from_matrix
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         sched = schedule_from_matrix(T.ring(4))
         setup = make_train_setup(cfg, mesh, mode="dsgd", schedule=sched,
@@ -146,7 +151,7 @@ def test_multi_step_scan_bitwise_equals_loop():
         sh = jax.tree.map(lambda s: NamedSharding(mesh, s), setup.param_specs,
                           is_leaf=lambda x: isinstance(x, P))
         k = 4
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(setup.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
             toks = jax.random.randint(jax.random.PRNGKey(1), (k, 4, 4, 32), 0, cfg.vocab_size)
             batches = {"tokens": toks, "labels": toks}
@@ -176,14 +181,14 @@ def test_fsdp_step_matches_loss_of_dsgd_complete():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("gemma-2b")
         toks = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (8, 32), 0, cfg.vocab_size))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             s_f = make_train_setup(cfg, mesh, mode="fsdp", lr=1e-2)
             p_f = jax.jit(s_f.init_params)(jax.random.PRNGKey(0))
             bf = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(toks)}
@@ -213,22 +218,25 @@ def test_online_w_matches_static_schedule_and_swaps_without_retrace():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import learn_topology, schedule_from_result
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         Pi = np.eye(2)[np.arange(4) % 2].astype(float)
         sched = schedule_from_result(learn_topology(Pi, budget=2, lam=0.5))
         W = jnp.asarray(sched.to_matrix(), jnp.float32)
+        # the hot-swap target is built beside W: same type, new value (an
+        # array made under set_mesh carries the mesh in its type)
+        W2 = jnp.full((4, 4), 0.25, jnp.float32)   # uniform W
 
         s_static = make_train_setup(cfg, mesh, mode="dsgd", schedule=sched, lr=2e-2)
         s_online = make_train_setup(cfg, mesh, mode="dsgd", online_w=True, lr=2e-2)
         sh = jax.tree.map(lambda s: NamedSharding(mesh, s), s_static.param_specs,
                           is_leaf=lambda x: isinstance(x, P))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(s_static.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
             toks = np.random.default_rng(0).integers(0, 50, size=(4, 2, 32))
             batch = {k: jnp.asarray(toks, jnp.int32) for k in ("tokens", "labels")}
@@ -247,8 +255,7 @@ def test_online_w_matches_static_schedule_and_swaps_without_retrace():
             msj = jax.jit(counted)
             batches = {k: jnp.stack([batch[k]] * 3) for k in batch}
             p, _, _ = msj(params, None, batches, W)
-            W2 = jnp.full((4, 4), 0.25, jnp.float32)   # hot swap: uniform W
-            p, _, losses2 = msj(p, None, batches, W2)
+            p, _, losses2 = msj(p, None, batches, W2)   # hot swap
             assert n_traces[0] == 1, n_traces          # swap retraced nothing
             assert np.isfinite(np.asarray(losses2)).all()
         print("ONLINE_W_OK", d)
@@ -265,7 +272,7 @@ def test_staged_pool_bitwise_equals_allgather_and_swaps_without_retrace():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import topology as T
         from repro.core.mixing import (BirkhoffSchedule, PermPool, PoolSwap,
@@ -273,7 +280,7 @@ def test_staged_pool_bitwise_equals_allgather_and_swaps_without_retrace():
                                        mix_arrays_sharded)
         from repro.train.lm_trainer import make_train_setup
 
-        mesh1 = make_compat_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+        mesh1 = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         sched = schedule_from_matrix(T.ring(8))
         pool = PermPool.from_schedule(sched, capacity=6)
         g, dropped = pool.project(sched)
@@ -283,9 +290,9 @@ def test_staged_pool_bitwise_equals_allgather_and_swaps_without_retrace():
         gj = jnp.asarray(g)
 
         def run(fn):
-            return jax.jit(shard_map(fn, mesh=mesh1, in_specs=(P("data"),),
-                                     out_specs=P("data"), axis_names={"data"},
-                                     check_vma=False))(x)
+            return jax.jit(jax.shard_map(fn, mesh=mesh1, in_specs=(P("data"),),
+                                         out_specs=P("data"), axis_names={"data"},
+                                         check_vma=False))(x)
 
         got_pool = np.asarray(run(lambda v: mix_ppermute_pool(v, gj, pool, "data")))
         got_ag = np.asarray(run(lambda v: mix_arrays_sharded(v, arrays, "data")))
@@ -293,7 +300,7 @@ def test_staged_pool_bitwise_equals_allgather_and_swaps_without_retrace():
         want = T.ring(8) @ np.asarray(x)
         assert np.allclose(got_pool, want, atol=1e-5)
 
-        mesh = make_compat_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         setup = make_train_setup(cfg, mesh, mode="dsgd", online_w=True,
                                  sharded_transport="pool", pool=pool, lr=1e-2)
@@ -301,7 +308,7 @@ def test_staged_pool_bitwise_equals_allgather_and_swaps_without_retrace():
         assert setup.comm_bytes_per_step > 0
         sh = jax.tree.map(lambda s: NamedSharding(mesh, s), setup.param_specs,
                           is_leaf=lambda x: isinstance(x, P))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(setup.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
             toks = jax.random.randint(jax.random.PRNGKey(1), (10, 8, 2, 32), 0,
                                       cfg.vocab_size)
@@ -361,34 +368,42 @@ def test_mix_dense_sharded_serialized_peak_memory():
     """The serialized all-gather contraction must never hold the gathered
     (n, P_total) stack live: compiled per-device temp memory stays within
     ~one gathered leaf (the PR-4 peak-memory fix, checked on the compiled
-    HLO's buffer assignment)."""
+    HLO's buffer assignment). XLA:CPU expands optimization barriers before
+    it schedules, so this CPU stand-in depends on an XLA flag: it turns
+    that pass off (a compiler setting no deployment uses) to see the order
+    the TPU scheduler keeps; the unserialized map must hold the whole
+    stack. The guard on the real compiler is
+    test_tpu_compile.py::test_serialized_gather_holds_one_leaf_on_four_chips."""
     out = run_with_devices("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import AxisType, make_compat_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.core.mixing import mix_dense_sharded
 
         n, n_leaves = 8, 6
-        mesh = make_compat_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
         leaves = {f"w{i}": jnp.zeros((n, 64, 257), jnp.float32)
                   for i in range(n_leaves)}
         W = jnp.eye(n, dtype=jnp.float32)
 
-        def f(p, w):
-            return shard_map(
-                lambda q: mix_dense_sharded(q, w, "data"),
-                mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
-                axis_names={"data"}, check_vma=False)(p)
+        def temp_bytes(serialize):
+            def f(p, w):
+                return jax.shard_map(
+                    lambda q: mix_dense_sharded(q, w, "data", serialize=serialize),
+                    mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
+                    axis_names={"data"}, check_vma=False)(p)
+            stats = jax.jit(f).lower(leaves, W).compile().memory_analysis()
+            return stats.temp_size_in_bytes
 
-        stats = jax.jit(f).lower(leaves, W).compile().memory_analysis()
         one_gathered_leaf = n * 64 * 257 * 4      # bytes, f32
         full_stack = n_leaves * one_gathered_leaf
-        temp = stats.temp_size_in_bytes
+        temp = temp_bytes(True)
         # one live gather (+ slack for the contraction buffer), NOT the stack
         assert temp <= 2 * one_gathered_leaf, (temp, one_gathered_leaf)
         assert temp < full_stack // 2, (temp, full_stack)
+        assert temp_bytes(False) >= full_stack
         print("PEAK_MEMORY_OK", temp, one_gathered_leaf, full_stack)
-    """)
+    """, xla_flags="--xla_disable_hlo_passes=cse_barrier_expander")
     assert "PEAK_MEMORY_OK" in out
 
 
@@ -400,7 +415,7 @@ def test_node_churn_end_to_end_online_mesh_trainer():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import learn_topology
         from repro.core.mixing import PermPool, schedule_from_result
@@ -424,7 +439,7 @@ def test_node_churn_end_to_end_online_mesh_trainer():
             detector=DriftDetector(threshold=1.05, warmup=1),
             pool=pool, pool_miss_tol=0.25)
 
-        mesh = make_compat_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         setup = make_train_setup(cfg, mesh, mode="dsgd", online_w=True,
                                  sharded_transport="pool", pool=pool, lr=1e-2)
@@ -438,7 +453,7 @@ def test_node_churn_end_to_end_online_mesh_trainer():
             return ctl.on_segment(t)
 
         g0, _ = pool.project(ref.schedule)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(setup.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
             toks = jax.random.randint(jax.random.PRNGKey(1), (steps, 8, 2, 32),
                                       0, cfg.vocab_size)
@@ -460,13 +475,13 @@ def test_node_churn_end_to_end_online_mesh_trainer():
 def test_online_w_rejects_invalid_configs():
     from repro.configs import get_smoke_config  # noqa: F401  (import-path smoke)
     code = """
-        import numpy as np, pytest
-        from repro.compat import AxisType, make_compat_mesh
+        import jax, numpy as np, pytest
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import learn_topology, schedule_from_result
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         Pi = np.eye(2)[np.arange(4) % 2].astype(float)
         sched = schedule_from_result(learn_topology(Pi, budget=2, lam=0.5))
@@ -500,7 +515,7 @@ def test_compressed_sharded_transports_agree_and_validate():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import topology as T
         from repro.core.compression import (Compressor, make_compressor,
@@ -511,7 +526,7 @@ def test_compressed_sharded_transports_agree_and_validate():
                                        mix_ppermute_pool, schedule_from_matrix)
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         sched = schedule_from_matrix(T.ring(8))
         pool = PermPool.from_schedule(sched, capacity=6)
         g, dropped = pool.project(sched)
@@ -523,9 +538,9 @@ def test_compressed_sharded_transports_agree_and_validate():
         e = jnp.asarray(rng.normal(size=(8, 37), scale=0.2), jnp.float32)
 
         def run(fn):
-            return jax.jit(shard_map(fn, mesh=mesh, in_specs=(P("data"), P("data")),
-                                     out_specs=(P("data"), P("data")),
-                                     axis_names={"data"}, check_vma=False))(x, e)
+            return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(P("data"), P("data")),
+                                         out_specs=(P("data"), P("data")),
+                                         axis_names={"data"}, check_vma=False))(x, e)
 
         for wire in ("bf16", "topk:0.25"):
             comp = make_compressor(wire)
@@ -546,15 +561,15 @@ def test_compressed_sharded_transports_agree_and_validate():
         ident = Compressor("identity")
         mi, ei = run(lambda v, m: mix_ppermute_pool_ef(v, m, gj, pool,
                                                        "data", ident))
-        plain = jax.jit(shard_map(
+        plain = jax.jit(jax.shard_map(
             lambda v: mix_ppermute_pool(v, gj, pool, "data"), mesh=mesh,
             in_specs=(P("data"),), out_specs=P("data"), axis_names={"data"},
             check_vma=False))(x)
         assert np.array_equal(np.asarray(mi), np.asarray(plain))
         assert np.array_equal(np.asarray(ei), np.asarray(e))  # ef untouched
 
-        mesh2 = make_compat_mesh((8, 1), ("data", "model"),
-                                 axis_types=(AxisType.Auto,) * 2)
+        mesh2 = jax.make_mesh((8, 1), ("data", "model"),
+                              axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke_config("qwen3-0.6b")
         for kwargs in ({"mode": "fsdp"},
                        {"mode": "dsgd_pod"},
@@ -586,14 +601,14 @@ def test_run_segments_checkpoint_resume_bitwise():
         import tempfile
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import topology as T
         from repro.core.mixing import schedule_from_matrix, schedule_to_arrays
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((8, 1), ("data", "model"),
-                                axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((8, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
         setup = make_train_setup(cfg, mesh, mode="dsgd", online_w=True, lr=1e-2)
         sh = jax.tree.map(lambda s: NamedSharding(mesh, s), setup.param_specs,
@@ -602,7 +617,7 @@ def test_run_segments_checkpoint_resume_bitwise():
         mix1 = schedule_to_arrays(
             schedule_from_matrix(0.5 * T.ring(8) + 0.5 * np.eye(8)), 4)
         hook = lambda t: mix1 if t == 3 else None   # swap BEFORE the crash
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(setup.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
             toks = jax.random.randint(jax.random.PRNGKey(1), (8, 8, 2, 32), 0,
                                       cfg.vocab_size)

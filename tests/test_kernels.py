@@ -1,8 +1,11 @@
-"""Per-kernel allclose suites against the pure-jnp oracles (interpret mode).
+"""Per-kernel allclose suites against the pure-jnp oracles (interpret mode),
+and the rule that picks interpret mode.
 
 Shape/dtype sweeps as required: parametrized grids + hypothesis-driven
 random shapes.
 """
+
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -10,8 +13,16 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
-from repro.kernels.gossip_mix import gossip_mix, gossip_mix_ref
+from repro.kernels.gossip_mix import (
+    gossip_mix,
+    gossip_mix_ref,
+    gossip_schedule,
+    gossip_schedule_ref,
+)
+from repro.kernels.interpret import resolve_interpret
+from repro.kernels.rglru_scan import rglru_scan
 
 
 # ---------------------------------------------------------------------------
@@ -112,3 +123,56 @@ def test_flash_attention_small_seq_fallback():
     out = flash_attention(q, k, v)
     ref = flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# gossip_schedule
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n", [5, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gossip_schedule_vs_ref(n, dtype):
+    rng = np.random.default_rng(n)
+    theta = jnp.asarray(rng.normal(size=(n, 4096)), dtype)
+    perms = jnp.asarray(np.stack([rng.permutation(n) for _ in range(3)]), jnp.int32)
+    coeffs = jnp.asarray([0.5, 0.3, 0.2], jnp.float32)
+    out = gossip_schedule(theta, coeffs, perms)
+    ref = gossip_schedule_ref(theta, coeffs, perms)
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    assert out.dtype == theta.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=tol, rtol=tol
+    )
+
+
+# ---------------------------------------------------------------------------
+# interpret mode: one rule for every kernel
+# ---------------------------------------------------------------------------
+
+def test_default_interpret_only_on_cpu():
+    assert jax.default_backend() == "cpu"
+    assert default_interpret() is True
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+
+
+def test_kernels_without_interpret_resolve_by_the_rule(monkeypatch):
+    # the kernel modules (their packages export functions of the same name)
+    fa_mod = importlib.import_module("repro.kernels.flash_attention.flash_attention")
+    rg_mod = importlib.import_module("repro.kernels.rglru_scan.rglru_scan")
+
+    seen = []
+
+    def spy(interpret):
+        seen.append(interpret)
+        return resolve_interpret(interpret)
+
+    monkeypatch.setattr(fa_mod, "resolve_interpret", spy)
+    monkeypatch.setattr(rg_mod, "resolve_interpret", spy)
+    jax.clear_caches()  # the jitted wrappers must trace again to reach the spy
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(1, 128, 2, 64)), jnp.float32)
+    flash_attention(q, q, q)
+    a = jnp.asarray(rng.uniform(0.5, 1.0, size=(1, 256, 512)), jnp.float32)
+    rglru_scan(a, a)
+    assert seen == [None, None]

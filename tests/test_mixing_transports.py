@@ -113,21 +113,21 @@ def test_ppermute_matches_schedule_stacked_multidevice():
     code = """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_compat_mesh, shard_map
         from repro.core import topology as T
         from repro.core.mixing import (schedule_from_matrix, mix_ppermute,
                                        mix_dense, mix_schedule_stacked)
 
         n = 8
-        mesh = make_compat_mesh((n,), ("data",))
+        mesh = jax.make_mesh((n,), ("data",))
         W = T.random_d_regular(n, 3, seed=4)
         sched = schedule_from_matrix(W)
         x = jnp.asarray(np.random.default_rng(0).normal(size=(n, 24)), jnp.float32)
 
         def gossip(v):
-            return shard_map(lambda p: mix_ppermute(p, sched, "data"),
-                             mesh=mesh, in_specs=(P("data"),),
-                             out_specs=P("data"), axis_names={"data"})(v)
+            return jax.shard_map(lambda p: mix_ppermute(p, sched, "data"),
+                                 mesh=mesh, in_specs=(P("data"),),
+                                 out_specs=P("data"), axis_names={"data"},
+                                 check_vma=False)(v)
 
         got = np.asarray(jax.jit(gossip)(x))
         Wj = jnp.asarray(sched.to_matrix(), jnp.float32)

@@ -628,15 +628,15 @@ def test_mesh_probes_bitwise_across_hot_swap():
     out = _run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import topology as T
         from repro.core.mixing import schedule_from_matrix, schedule_to_arrays
         from repro.obs import HealthProbes, RetraceGuard
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((8, 1), ("data", "model"),
-                                axis_types=(AxisType.Auto,)*2)
+        mesh = jax.make_mesh((8, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,)*2)
         cfg = get_smoke_config("qwen3-0.6b")
 
         # probe validation at setup time: tau_bar is a simulator probe,
@@ -662,7 +662,7 @@ def test_mesh_probes_bitwise_across_hot_swap():
         mix1 = schedule_to_arrays(
             schedule_from_matrix(0.5 * T.ring(8) + 0.5 * np.eye(8)), 4)
         hook = lambda t: mix1 if t == 3 else None
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(s_off.init_params, out_shardings=sh)(
                 jax.random.PRNGKey(0))
             toks = jax.random.randint(jax.random.PRNGKey(1), (8, 8, 2, 32), 0,

@@ -324,7 +324,7 @@ def test_sharded_stale_transports_zero_delay_bitwise():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import AxisType, make_compat_mesh, set_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.core import topology as T
         from repro.core.mixing import (
             PermPool, mix_arrays_sharded, mix_arrays_sharded_stale,
@@ -335,7 +335,7 @@ def test_sharded_stale_transports_zero_delay_bitwise():
         )
 
         n, Pdim, depth, steps = 8, 16, 3, 4
-        mesh = make_compat_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
         sched = schedule_from_matrix(0.6 * T.ring(n) + 0.4 * np.eye(n))
         arrays = schedule_to_arrays(sched, 8)
         pool = PermPool.from_schedule(sched, capacity=8)
@@ -371,13 +371,13 @@ def test_sharded_stale_transports_zero_delay_bitwise():
             return (jnp.stack(f_ag), jnp.stack(s_ag), jnp.stack(f_pl),
                     jnp.stack(s_pl), late_ag, late_pl)
 
-        with set_mesh(mesh):
-            run = jax.jit(shard_map(
+        with jax.set_mesh(mesh):
+            run = jax.jit(jax.shard_map(
                 rollout, mesh=mesh,
                 in_specs=(P(None, "data"), P()),
                 out_specs=tuple(P(None, "data") for _ in range(4))
                           + (P("data"), P("data")),
-                axis_names={"data"},
+                axis_names={"data"}, check_vma=False,
             ))
             f_ag, s_ag, f_pl, s_pl, late_ag, late_pl = run(xs, delays)
 
@@ -409,7 +409,7 @@ def test_lm_stale_ring_and_ef_share_one_carry():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_compat_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core import topology as T
         from repro.core.mixing import (
@@ -417,8 +417,8 @@ def test_lm_stale_ring_and_ef_share_one_carry():
         )
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((8, 1), ("data", "model"),
-                                axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((8, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke_config("qwen3-0.6b")
         sched = schedule_from_matrix(0.6 * T.ring(8) + 0.4 * np.eye(8))
         arrays = schedule_to_arrays(sched, 8)
@@ -438,7 +438,7 @@ def test_lm_stale_ring_and_ef_share_one_carry():
             sh = jax.tree.map(lambda sp: NamedSharding(mesh, sp),
                               s.param_specs,
                               is_leaf=lambda x: isinstance(x, P))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 p = jax.jit(s.init_params, out_shardings=sh)(jax.random.PRNGKey(0))
                 o = s.init_opt_state(p)
             return s, p, o
@@ -447,10 +447,10 @@ def test_lm_stale_ring_and_ef_share_one_carry():
 
         # fresh vs staleness-at-zero-delays: bitwise
         s0, p0, o0 = build(compression="bf16")
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             base = s0.run_segments(p0, o0, batches, arrays, segment_len=seg)
         s1, p1, o1 = build(compression="bf16", staleness=pol)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             zero = s1.run_segments(p1, o1, batches, arrays, segment_len=seg)
         assert np.array_equal(base["losses"], zero["losses"])
         assert base["comm"]["total_bytes"] == zero["comm"]["total_bytes"]
@@ -464,7 +464,7 @@ def test_lm_stale_ring_and_ef_share_one_carry():
         )
         s2, p2, o2 = build(compression="bf16", staleness=pol)
         hooks = iter([swapped])
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             live = s2.run_segments(
                 p2, o2, batches, arrays, segment_len=seg,
                 delays=delays.astype(np.int32),
@@ -481,15 +481,15 @@ def test_lm_stale_ring_and_ef_share_one_carry():
 
 def test_lm_staleness_validation():
     out = run_with_devices("""
-        import numpy as np
+        import jax, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import AxisType, make_compat_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.core.mixing import StragglerPolicy
         from repro.train.lm_trainer import make_train_setup
 
-        mesh = make_compat_mesh((8, 1), ("data", "model"),
-                                axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((8, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke_config("qwen3-0.6b")
         pol = StragglerPolicy(mode="wait", tau_max=2)
         for kw, exc in (
