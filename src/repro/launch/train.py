@@ -19,7 +19,6 @@ losses, the setup, the final parameters, the mesh and the schedule.
 from __future__ import annotations
 
 import argparse
-import time
 from typing import Sequence
 
 import jax
@@ -33,6 +32,7 @@ from repro.core.mixing import schedule_from_matrix
 from repro.data.tokens import DomainSkewCorpus, TokenBatcher
 from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_device_mesh
+from repro.obs.trace import Tracer
 from repro.train.checkpoints import CheckpointManager
 from repro.train.lm_trainer import make_train_setup
 
@@ -96,7 +96,9 @@ def run(argv: Sequence[str] | None = None) -> dict:
     ``params`` (final, stacked per node), ``mesh``, ``schedule`` (None for
     the complete graph), ``cfg``, ``batch0`` (the step-0 batch), and the
     host clock's ``compile_s`` (the step's compile), ``step_s`` and
-    ``batch_s`` (one entry per step).
+    ``batch_s`` (one entry per step): the durations of the ``train.compile``,
+    ``train.step`` and ``train.batch`` spans, which under
+    ``jax.profiler.trace`` also sit on the profiler's clock.
     """
     ap = _parser()
     args = ap.parse_args(argv)
@@ -138,30 +140,29 @@ def run(argv: Sequence[str] | None = None) -> dict:
     ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
 
     losses: list[float] = []
-    step_s: list[float] = []
-    batch_s: list[float] = []
-    step_fn = batch0 = compile_s = None
+    tracer = Tracer(capacity=2 * args.steps + 1)  # holds every span of the run
+    step_fn = batch0 = None
     with jax.set_mesh(mesh):
         params = jax.jit(setup.init_params, out_shardings=shardings)(
             jax.random.PRNGKey(PARAM_SEED)
         )
         for t in range(args.steps):
-            tic = time.perf_counter()
-            batch = _batch(cfg, *batcher.next_batch(t))
-            batch_s.append(time.perf_counter() - tic)
+            with tracer.span("train.batch"):
+                batch = _batch(cfg, *batcher.next_batch(t))
             if step_fn is None:
                 batch0 = batch
-                tic = time.perf_counter()
-                step_fn = jax.jit(setup.train_step).lower(params, None, batch).compile()
-                compile_s = time.perf_counter() - tic
-                print(f"compiled the step in {compile_s:.1f}s", flush=True)
-            tic = time.perf_counter()
-            params, _, loss = step_fn(params, None, batch)
-            losses.append(float(loss))  # waits for the step
-            step_s.append(time.perf_counter() - tic)
+                with tracer.span("train.compile"):
+                    step_fn = jax.jit(setup.train_step).lower(
+                        params, None, batch).compile()
+                print(f"compiled the step in "
+                      f"{tracer.total_s('train.compile'):.1f}s", flush=True)
+            with tracer.span("train.step"):
+                params, _, loss = step_fn(params, None, batch)
+                losses.append(float(loss))  # waits for the step
             if t % 5 == 0 or t == args.steps - 1:
+                step, batch_rec = tracer.spans()[-1], tracer.spans("train.batch")[-1]
                 print(f"step {t:4d}  loss {losses[-1]:.4f}  "
-                      f"({step_s[-1]:.2f}s step, {batch_s[-1]:.2f}s batch)",
+                      f"({step.duration_s:.2f}s step, {batch_rec.duration_s:.2f}s batch)",
                       flush=True)
         if ckpt is not None:
             ckpt.save(args.steps, jax.device_get(params))
@@ -176,9 +177,10 @@ def run(argv: Sequence[str] | None = None) -> dict:
         "schedule": schedule,
         "cfg": cfg,
         "batch0": batch0,
-        "compile_s": compile_s,
-        "step_s": step_s,
-        "batch_s": batch_s,
+        "compile_s": (tracer.total_s("train.compile")
+                      if step_fn is not None else None),
+        "step_s": [r.duration_s for r in tracer.spans("train.step")],
+        "batch_s": [r.duration_s for r in tracer.spans("train.batch")],
     }
 
 

@@ -198,66 +198,68 @@ def attention(
     B, S, _ = x.shape
     dh = cfg.resolved_head_dim
     eff_window = window if window is not None else (cfg.sliding_window if local else None)
-    q, k, v = _project_qkv(params, cfg, x)
-    cos, sin = rotary_embedding(positions, dh, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("qkv"):
+        q, k, v = _project_qkv(params, cfg, x)
+        cos, sin = rotary_embedding(positions, dh, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if cache is None:
-        if impl == "pallas" and causal:
-            from repro.kernels.flash_attention import ops as fa_ops
+    with jax.named_scope("sdpa"):
+        if cache is None:
+            if impl == "pallas" and causal:
+                from repro.kernels.flash_attention import ops as fa_ops
 
-            out = fa_ops.flash_attention(
-                q, k, v, causal=True, window=eff_window,
-                softcap=cfg.attn_logit_softcap,
-            )
-        elif causal and S > _CHUNK_THRESHOLD and S % _CHUNK_Q == 0:
-            out = _sdpa_chunked(q, k, v, cfg, eff_window)
+                out = fa_ops.flash_attention(
+                    q, k, v, causal=True, window=eff_window,
+                    softcap=cfg.attn_logit_softcap,
+                )
+            elif causal and S > _CHUNK_THRESHOLD and S % _CHUNK_Q == 0:
+                out = _sdpa_chunked(q, k, v, cfg, eff_window)
+            else:
+                mask = _causal_mask(S, S, eff_window) if causal else None
+                out = _sdpa(q, k, v, mask, cfg)
+            new_cache = None
+        elif S > 1:
+            # Prefill (multi-token append, assumed from a fresh cache): compute
+            # the chunk's attention on the full-sequence path -- the chunked
+            # flash-style implementation, NOT a quadratic attend against the
+            # (possibly much larger) cache buffer -- then write the cache.
+            # (A window ring also cannot serve as the source while being
+            # filled: early keys may be evicted before later queries need them.)
+            if causal and S > _CHUNK_THRESHOLD and S % _CHUNK_Q == 0:
+                out = _sdpa_chunked(q, k, v, cfg, eff_window)
+            else:
+                mask = _causal_mask(S, S, eff_window) if causal else None
+                out = _sdpa(q, k, v, mask, cfg)
+            if local or window is not None:
+                new_cache = update_window_cache(cache, k, v)
+            else:
+                new_cache = update_full_cache(cache, k, v)
         else:
-            mask = _causal_mask(S, S, eff_window) if causal else None
-            out = _sdpa(q, k, v, mask, cfg)
-        new_cache = None
-    elif S > 1:
-        # Prefill (multi-token append, assumed from a fresh cache): compute
-        # the chunk's attention on the full-sequence path -- the chunked
-        # flash-style implementation, NOT a quadratic attend against the
-        # (possibly much larger) cache buffer -- then write the cache.
-        # (A window ring also cannot serve as the source while being
-        # filled: early keys may be evicted before later queries need them.)
-        if causal and S > _CHUNK_THRESHOLD and S % _CHUNK_Q == 0:
-            out = _sdpa_chunked(q, k, v, cfg, eff_window)
-        else:
-            mask = _causal_mask(S, S, eff_window) if causal else None
-            out = _sdpa(q, k, v, mask, cfg)
-        if local or window is not None:
-            new_cache = update_window_cache(cache, k, v)
-        else:
-            new_cache = update_full_cache(cache, k, v)
-    else:
-        # positions: (B, S) absolute positions of the new tokens.
-        qpos = positions[:, :, None]  # (B, Sq, 1)
-        if not (local or window is not None):
-            new_cache = update_full_cache(cache, k, v)
-            Sk = new_cache["k"].shape[1]
-            kpos = jnp.arange(Sk)[None, None, :]  # (1, 1, Sk)
-            mask = kpos <= qpos  # (B, Sq, Sk)
-            out = _sdpa(q, new_cache["k"], new_cache["v"], mask[:, None], cfg)
-        else:  # window ring buffer
-            new_cache = update_window_cache(cache, k, v)
-            W = new_cache["k"].shape[1]
-            slot = jnp.arange(W)
-            idx = new_cache["index"]  # absolute positions written so far
-            # absolute position held by each ring slot after the write:
-            # largest value < idx congruent to the slot modulo W.
-            abs_pos = (idx - 1) - jnp.mod(idx - 1 - slot, W)  # (W,)
-            abs_pos = abs_pos[None, None, :]  # (1, 1, W)
-            mask = (abs_pos >= 0) & (abs_pos <= qpos)
-            if eff_window is not None:
-                mask = mask & (abs_pos > qpos - eff_window)
-            out = _sdpa(q, new_cache["k"], new_cache["v"], mask[:, None], cfg)
-
-    B, Sq = out.shape[:2]
-    out = out.reshape(B, Sq, -1) @ params["wo"]
+            # positions: (B, S) absolute positions of the new tokens.
+            qpos = positions[:, :, None]  # (B, Sq, 1)
+            if not (local or window is not None):
+                new_cache = update_full_cache(cache, k, v)
+                Sk = new_cache["k"].shape[1]
+                kpos = jnp.arange(Sk)[None, None, :]  # (1, 1, Sk)
+                mask = kpos <= qpos  # (B, Sq, Sk)
+                out = _sdpa(q, new_cache["k"], new_cache["v"], mask[:, None], cfg)
+            else:  # window ring buffer
+                new_cache = update_window_cache(cache, k, v)
+                W = new_cache["k"].shape[1]
+                slot = jnp.arange(W)
+                idx = new_cache["index"]  # absolute positions written so far
+                # absolute position held by each ring slot after the write:
+                # largest value < idx congruent to the slot modulo W.
+                abs_pos = (idx - 1) - jnp.mod(idx - 1 - slot, W)  # (W,)
+                abs_pos = abs_pos[None, None, :]  # (1, 1, W)
+                mask = (abs_pos >= 0) & (abs_pos <= qpos)
+                if eff_window is not None:
+                    mask = mask & (abs_pos > qpos - eff_window)
+                out = _sdpa(q, new_cache["k"], new_cache["v"], mask[:, None], cfg)
+    with jax.named_scope("out"):
+        B, Sq = out.shape[:2]
+        out = out.reshape(B, Sq, -1) @ params["wo"]
     return out, new_cache
 
 
@@ -297,6 +299,7 @@ def init_mla_attention(key: jax.Array, cfg: ModelConfig) -> PyTree:
     return params
 
 
+@jax.named_scope("sdpa")
 def _mla_attend(
     params: PyTree,
     cfg: ModelConfig,
@@ -325,6 +328,7 @@ def _mla_attend(
     return out.reshape(B, Sq, H * m.v_head_dim).astype(q_nope.dtype)
 
 
+@jax.named_scope("sdpa")
 def _mla_attend_chunked(
     params: PyTree,
     cfg: ModelConfig,

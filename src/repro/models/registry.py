@@ -60,7 +60,8 @@ def loss_fn(params: PyTree, cfg: ModelConfig, batch: dict, impl: str = "xla"):
         logits, _, _ = whisper.whisper_forward(
             params, cfg, batch["frames"], batch["tokens"]
         )
-        loss = transformer.softmax_xent(logits, batch["labels"])
+        with jax.named_scope("lm_head"):
+            loss = transformer.softmax_xent(logits, batch["labels"])
         return loss, {"nll": loss, "aux": jnp.zeros((), jnp.float32)}
     return transformer.lm_loss(
         params, cfg, batch["tokens"], batch["labels"],

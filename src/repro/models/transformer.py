@@ -120,27 +120,29 @@ def _layer_forward(
     aux = jnp.zeros((), jnp.float32)
     new_cache = None
     if kind in _ATTN_KINDS:
-        h = rms_norm(lp["ln1"], x, cfg.norm_eps)
-        local = kind == "local_attn" or window_override is not None
-        if cfg.mla is not None:
-            win = window_override if window_override is not None else (
-                cfg.sliding_window if kind == "local_attn" else None
-            )
-            attn_out, new_cache = mla_attention(
-                lp["attn"], cfg, h, positions=positions, cache=cache_layer, window=win
-            )
-        else:
-            attn_out, new_cache = attention(
-                lp["attn"], cfg, h,
-                positions=positions,
-                local=local,
-                window=window_override,
-                cache=cache_layer,
-                impl=impl,
-            )
-        if cfg.post_block_norms:
-            attn_out = rms_norm(lp["post_ln1"], attn_out, cfg.norm_eps)
-        x = x + attn_out
+        with jax.named_scope("attn"):
+            h = rms_norm(lp["ln1"], x, cfg.norm_eps)
+            local = kind == "local_attn" or window_override is not None
+            if cfg.mla is not None:
+                win = window_override if window_override is not None else (
+                    cfg.sliding_window if kind == "local_attn" else None
+                )
+                attn_out, new_cache = mla_attention(
+                    lp["attn"], cfg, h, positions=positions, cache=cache_layer,
+                    window=win,
+                )
+            else:
+                attn_out, new_cache = attention(
+                    lp["attn"], cfg, h,
+                    positions=positions,
+                    local=local,
+                    window=window_override,
+                    cache=cache_layer,
+                    impl=impl,
+                )
+            if cfg.post_block_norms:
+                attn_out = rms_norm(lp["post_ln1"], attn_out, cfg.norm_eps)
+            x = x + attn_out
     elif kind == "mlstm":
         x, new_cache = mlstm_block(lp["block"], cfg, x, cache_layer)
     elif kind == "slstm":
@@ -149,14 +151,15 @@ def _layer_forward(
         x, new_cache = rglru_block(lp["block"], cfg, x, cache_layer)
 
     if cfg.d_ff > 0 and kind not in ("mlstm", "slstm"):
-        h = rms_norm(lp["ln2"], x, cfg.norm_eps)
-        if cfg.moe is not None:
-            mlp_out, aux = moe_forward(lp["mlp"], cfg, h)
-        else:
-            mlp_out = mlp_forward(lp["mlp"], h, cfg.mlp_type)
-        if cfg.post_block_norms:
-            mlp_out = rms_norm(lp["post_ln2"], mlp_out, cfg.norm_eps)
-        x = x + mlp_out
+        with jax.named_scope("mlp"):
+            h = rms_norm(lp["ln2"], x, cfg.norm_eps)
+            if cfg.moe is not None:
+                mlp_out, aux = moe_forward(lp["mlp"], cfg, h)
+            else:
+                mlp_out = mlp_forward(lp["mlp"], h, cfg.mlp_type)
+            if cfg.post_block_norms:
+                mlp_out = rms_norm(lp["post_ln2"], mlp_out, cfg.norm_eps)
+            x = x + mlp_out
     return x, new_cache, aux
 
 
@@ -230,7 +233,8 @@ def forward(
 
     Returns (logits | hidden, new_cache, moe_aux_loss).
     """
-    x = embed(params["embed"], tokens, cfg)
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], tokens, cfg)
     if image_embeds is not None:
         x = jnp.concatenate([image_embeds.astype(x.dtype), x], axis=1)
     B, S, _ = x.shape
@@ -416,7 +420,8 @@ def lm_loss(
     )
     if image_embeds is not None:
         hidden = hidden[:, image_embeds.shape[1] :, :]
-    loss = fused_unembed_xent(params, cfg, hidden, labels)
+    with jax.named_scope("lm_head"):
+        loss = fused_unembed_xent(params, cfg, hidden, labels)
     total = loss
     if cfg.moe is not None:
         total = total + cfg.moe.router_aux_coef * aux

@@ -5,9 +5,10 @@ Three layers (see ``docs/observability.md``):
 
 * :mod:`repro.obs.trace`  -- :class:`Tracer`: nestable wall-clock spans
   on monotonic clocks, a bounded in-memory ring + JSONL sink, and a
-  Chrome/Perfetto trace-event exporter. Threaded through the segment
-  drivers, the online refresh controller, the fault injector, and the
-  benchmark harness.
+  Chrome/Perfetto trace-event exporter; each span is also a
+  ``jax.profiler.TraceAnnotation``, on the profiler's clock. Threaded
+  through the segment drivers, the online refresh controller, the fault
+  injector, and the training launcher (``launch/train.run``).
 * :mod:`repro.obs.probes` -- :class:`HealthProbes`: the paper's
   convergence-predicting quantities (consensus distance, Assumption-4
   gradient deviation, Prop. 2 tau_bar at the live Pi_hat) computed

@@ -973,8 +973,12 @@ def make_train_setup(
 
     def _step_impl(params, momentum_state, batch, mix_w=None, delays=None):
         if node_axis is None:
-            loss, grads = grad_of(params, batch)
-            new_params, new_m = _sgd_update(params, grads, momentum_state, lr, momentum)
+            with jax.named_scope("dsgd.grad"):
+                loss, grads = grad_of(params, batch)
+            with jax.named_scope("dsgd.update"):
+                new_params, new_m = _sgd_update(
+                    params, grads, momentum_state, lr, momentum
+                )
             return new_params, new_m, loss
 
         if mode == "dsgd_pod":
@@ -985,8 +989,12 @@ def make_train_setup(
             # SPMD partitioner -- see EXPERIMENTS.md.)
             import numpy as _np
 
-            losses, grads = jax.vmap(grad_of)(params, batch)
-            half, new_m = _sgd_update(params, grads, momentum_state, lr, momentum)
+            with jax.named_scope("dsgd.grad"):
+                losses, grads = jax.vmap(grad_of)(params, batch)
+            with jax.named_scope("dsgd.update"):
+                half, new_m = _sgd_update(
+                    params, grads, momentum_state, lr, momentum
+                )
             if online_w:
                 if isinstance(mix_w, ScheduleArrays) or getattr(mix_w, "ndim", 2) != 2:
                     raise TypeError(
@@ -994,19 +1002,21 @@ def make_train_setup(
                         "axis: pass mix_w as a dense (n, n) W (pool gammas / "
                         "ScheduleArrays are dsgd-mode operands)"
                     )
-                W_pod = mix_w.astype(jnp.float32)
-            else:
-                W_pod = (
-                    jnp.asarray(schedule.to_matrix(), jnp.float32)
-                    if schedule is not None
-                    else jnp.full((n_nodes, n_nodes), 1.0 / n_nodes, jnp.float32)
+            with jax.named_scope("dsgd.gossip"):
+                if online_w:
+                    W_pod = mix_w.astype(jnp.float32)
+                else:
+                    W_pod = (
+                        jnp.asarray(schedule.to_matrix(), jnp.float32)
+                        if schedule is not None
+                        else jnp.full((n_nodes, n_nodes), 1.0 / n_nodes, jnp.float32)
+                    )
+                mixed = jax.tree_util.tree_map(
+                    lambda x: jnp.einsum(
+                        "pq,q...->p...", W_pod, x.astype(jnp.float32)
+                    ).astype(x.dtype),
+                    half,
                 )
-            mixed = jax.tree_util.tree_map(
-                lambda x: jnp.einsum(
-                    "pq,q...->p...", W_pod, x.astype(jnp.float32)
-                ).astype(x.dtype),
-                half,
-            )
             return mixed, new_m, losses.mean()
 
         # The node axis is *manual* (shard_map over `node_axis`): each shard
@@ -1047,8 +1057,10 @@ def make_train_setup(
             # In dsgd_pod mode the within-pod `data` axis stays automatic:
             # GSPMD data-parallelizes the loss/grad over it (the batch input
             # sharding carries P(pod, data, ...)).
-            loss, grads = grad_of(p1, b1)
-            half, new_m = _sgd_update(p1, grads, m1, lr, momentum)
+            with jax.named_scope("dsgd.grad"):
+                loss, grads = grad_of(p1, b1)
+            with jax.named_scope("dsgd.update"):
+                half, new_m = _sgd_update(p1, grads, m1, lr, momentum)
 
             def do_mix(h):
                 if online_w:
@@ -1085,78 +1097,80 @@ def make_train_setup(
                     "gossip_every > 1 needs a step counter: pass "
                     "momentum_state={'step': jnp.zeros((), jnp.int32), 'm': ...}"
                 )
-            new_e1 = None
-            new_st1 = None
-            if staleness is not None:
-                # bounded-delay dispatch: same transport fork as do_mix,
-                # with the sender-side ring and this step's delay vector
-                # threaded as data (gossip_every > 1 was rejected at
-                # build time, so every step both pushes and mixes)
-                w, d = w_args
-                stale_dense_msg = (
-                    "staleness needs a per-sender payload to delay: pass "
-                    "mix_w as ScheduleArrays (allgather) or pool gammas, "
-                    "not a dense (n, n) W"
-                )
-                if compressor is not None:
-                    if resolved_transport == "pool":
-                        mixed, new_e1, new_st1 = mix_ppermute_pool_stale_ef(
-                            half, e1, st1, w, pool, d, node_axis, compressor
-                        )
-                    elif isinstance(w, ScheduleArrays):
-                        mixed, new_e1, new_st1 = mix_arrays_sharded_stale_ef(
-                            half, e1, st1, w, d, node_axis, compressor
+            with jax.named_scope("dsgd.gossip"):
+                new_e1 = None
+                new_st1 = None
+                if staleness is not None:
+                    # bounded-delay dispatch: same transport fork as do_mix,
+                    # with the sender-side ring and this step's delay vector
+                    # threaded as data (gossip_every > 1 was rejected at
+                    # build time, so every step both pushes and mixes)
+                    w, d = w_args
+                    stale_dense_msg = (
+                        "staleness needs a per-sender payload to delay: pass "
+                        "mix_w as ScheduleArrays (allgather) or pool gammas, "
+                        "not a dense (n, n) W"
+                    )
+                    if compressor is not None:
+                        if resolved_transport == "pool":
+                            mixed, new_e1, new_st1 = mix_ppermute_pool_stale_ef(
+                                half, e1, st1, w, pool, d, node_axis, compressor
+                            )
+                        elif isinstance(w, ScheduleArrays):
+                            mixed, new_e1, new_st1 = mix_arrays_sharded_stale_ef(
+                                half, e1, st1, w, d, node_axis, compressor
+                            )
+                        else:
+                            raise TypeError(stale_dense_msg)
+                    else:
+                        if resolved_transport == "pool":
+                            mixed, new_st1 = mix_ppermute_pool_stale(
+                                half, st1, w, pool, d, node_axis
+                            )
+                        elif isinstance(w, ScheduleArrays):
+                            mixed, new_st1 = mix_arrays_sharded_stale(
+                                half, st1, w, d, node_axis
+                            )
+                        else:
+                            raise TypeError(stale_dense_msg)
+                elif compressor is not None:
+                    if gossip_every > 1:
+                        mixed, new_e1 = jax.lax.cond(
+                            jnp.mod(step, gossip_every) == 0,
+                            do_mix_ef,
+                            lambda he: he,
+                            (half, e1),
                         )
                     else:
-                        raise TypeError(stale_dense_msg)
-                else:
-                    if resolved_transport == "pool":
-                        mixed, new_st1 = mix_ppermute_pool_stale(
-                            half, st1, w, pool, d, node_axis
-                        )
-                    elif isinstance(w, ScheduleArrays):
-                        mixed, new_st1 = mix_arrays_sharded_stale(
-                            half, st1, w, d, node_axis
-                        )
-                    else:
-                        raise TypeError(stale_dense_msg)
-            elif compressor is not None:
-                if gossip_every > 1:
-                    mixed, new_e1 = jax.lax.cond(
-                        jnp.mod(step, gossip_every) == 0,
-                        do_mix_ef,
-                        lambda he: he,
-                        (half, e1),
+                        mixed, new_e1 = do_mix_ef((half, e1))
+                elif gossip_every > 1:
+                    mixed = jax.lax.cond(
+                        jnp.mod(step, gossip_every) == 0, do_mix, lambda h: h, half
                     )
                 else:
-                    mixed, new_e1 = do_mix_ef((half, e1))
-            elif gossip_every > 1:
-                mixed = jax.lax.cond(
-                    jnp.mod(step, gossip_every) == 0, do_mix, lambda h: h, half
-                )
-            else:
-                mixed = do_mix(half)
+                    mixed = do_mix(half)
             loss_mean = jax.lax.pmean(loss, node_axis)
             if probes is not None:
-                # collective twins of the stacked-host probes: psum over
-                # nodes of this shard's squared distance to the pmean.
-                # Pure value computations on this step's mixed params /
-                # grads -- extra replicated outputs, zero extra traces.
-                def spread_sq(tree):
-                    tot = jnp.zeros((), jnp.float32)
-                    for x in jax.tree_util.tree_leaves(tree):
-                        xf = x.astype(jnp.float32)
-                        mu = jax.lax.pmean(xf, node_axis)
-                        tot = tot + jax.lax.psum(
-                            jnp.sum(jnp.square(xf - mu)), node_axis
-                        )
-                    return tot
+                with jax.named_scope("dsgd.probes"):
+                    # collective twins of the stacked-host probes: psum over
+                    # nodes of this shard's squared distance to the pmean.
+                    # Pure value computations on this step's mixed params /
+                    # grads -- extra replicated outputs, zero extra traces.
+                    def spread_sq(tree):
+                        tot = jnp.zeros((), jnp.float32)
+                        for x in jax.tree_util.tree_leaves(tree):
+                            xf = x.astype(jnp.float32)
+                            mu = jax.lax.pmean(xf, node_axis)
+                            tot = tot + jax.lax.psum(
+                                jnp.sum(jnp.square(xf - mu)), node_axis
+                            )
+                        return tot
 
-                loss_out = {"loss": loss_mean}
-                if probes.consensus:
-                    loss_out["consensus"] = spread_sq(mixed)
-                if probes.grad_dev:
-                    loss_out["grad_dev"] = spread_sq(grads) / n_nodes
+                    loss_out = {"loss": loss_mean}
+                    if probes.consensus:
+                        loss_out["consensus"] = spread_sq(mixed)
+                    if probes.grad_dev:
+                        loss_out["grad_dev"] = spread_sq(grads) / n_nodes
             else:
                 loss_out = loss_mean
             new_m_tree = unsqueeze(new_m) if momentum > 0.0 else m_tree

@@ -39,6 +39,30 @@ def test_run_one_device_smoke(monkeypatch, tmp_path, cache_config):
     assert out["compile_s"] > 0 and len(out["step_s"]) == len(out["batch_s"]) == 2
 
 
+def test_run_times_are_its_spans_on_the_profiler_clock(monkeypatch, tmp_path,
+                                                       cache_config):
+    """Under jax.profiler.trace the launcher's batch, compile and step times
+    are host events ``train.batch``, ``train.compile`` and ``train.step``,
+    each as long as the time ``run`` returns for it."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        out = run(SMOKE_ARGS)
+    path = next((tmp_path / "trace").rglob("*.xplane.pb"))
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(e)
+    for name, times in [("train.batch", out["batch_s"]),
+                        ("train.step", out["step_s"]),
+                        ("train.compile", [out["compile_s"]])]:
+        seen = sorted(events[name], key=lambda e: e.start_ns)
+        assert len(seen) == len(times)
+        for e, s in zip(seen, times):
+            assert s - 5e-3 <= e.duration_ns * 1e-9 <= s + 1e-4
+
+
 def test_run_rejects_a_mesh_that_is_not_the_devices(cache_config):
     with pytest.raises(SystemExit):
         run(SMOKE_ARGS + ["--data", "2"])

@@ -3,7 +3,8 @@
 Covers the three layers plus their driver integrations:
 
 * ``Tracer`` -- span nesting/ordering, thread merging, the bounded
-  ring, JSONL round-trips, and the Perfetto export schema;
+  ring, JSONL round-trips, the Perfetto export schema, and the spans'
+  twins on the profiler's clock;
 * ``HealthProbes`` -- every probe checked against its host-side
   reference (``train.metrics.consensus_distance``,
   ``core.heterogeneity.local_heterogeneity`` / ``tau_bar_label_skew``,
@@ -28,6 +29,7 @@ import subprocess
 import sys
 import textwrap
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -187,13 +189,47 @@ def test_jsonl_sink_roundtrip(tmp_path):
         assert SpanRecord.from_dict(r.to_dict()) == r
 
 
-def test_write_jsonl_export_roundtrip(tmp_path):
-    tr = Tracer()
-    with tr.span("a", x=np.float32(1.5)):  # numpy attr must serialize
-        pass
-    path = tr.write_jsonl(str(tmp_path / "export.jsonl"))
-    recs = read_jsonl(path)
+def test_jsonl_sink_serializes_numpy_attrs(tmp_path):
+    sink = str(tmp_path / "trace.jsonl")
+    with Tracer(sink_path=sink) as tr:
+        with tr.span("a", x=np.float32(1.5)):  # numpy attr must serialize
+            pass
+    recs = read_jsonl(sink)
     assert len(recs) == 1 and recs[0].attrs["x"] == 1.5
+
+
+def _host_events(logdir) -> dict:
+    """{name: [events]} on the ``/host:CPU`` plane of the one trace under
+    ``logdir``."""
+    path = next(logdir.rglob("*.xplane.pb"))
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(e)
+    return events
+
+
+def test_spans_land_on_the_profiler_clock(tmp_path):
+    """Under jax.profiler.trace an enabled tracer's spans and instants are
+    host events of the same name, nested as the spans are and no longer
+    than the tracer measured; a disabled tracer's leave nothing."""
+    tr, off = Tracer(), Tracer(enabled=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("obs.outer"):
+            with tr.span("obs.inner", k=1):
+                time.sleep(0.01)
+            tr.instant("obs.mark")
+        with off.span("obs.off"):
+            off.instant("obs.off_mark")
+    events = _host_events(tmp_path)
+    assert {"obs.outer", "obs.inner", "obs.mark"} <= set(events)
+    assert not {"obs.off", "obs.off_mark"} & set(events)
+    (outer,), (inner,) = events["obs.outer"], events["obs.inner"]
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns
+    assert 0.01 <= inner.duration_ns * 1e-9 <= tr.spans("obs.inner")[0].duration_s
 
 
 def test_perfetto_export_schema(tmp_path):
