@@ -2,17 +2,22 @@
 and cross-attention, with full-sequence and cached-decode paths.
 
 Layout conventions: activations (B, S, D); q/k/v (B, S, H, Dh). Keys are
-rotated (RoPE) before caching. The full-sequence path can route through the
-Pallas flash-attention kernel (``impl='pallas'``) or plain XLA einsums
-(``impl='xla'``, default -- this is what the dry-run lowers).
+rotated (RoPE) before caching. The full-sequence causal path runs the Pallas
+flash-attention kernel when ``impl='pallas'``, and by default (``impl='xla'``)
+above 2048 tokens on a TPU when the head dim is a multiple of 128; otherwise
+plain XLA einsums (chunked above 2048 tokens).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
+
+from repro.kernels.flash_attention import flash_attention
 
 from .common import MLAConfig, ModelConfig, dtype_of, truncated_normal
 from .kvcache import (
@@ -125,8 +130,9 @@ def _sdpa_chunked(
 ) -> jax.Array:
     """Flash-style causal attention in pure XLA: scan over q chunks with a
     full-k online-softmax per chunk. Peak temp is O(B*H*chunk_q*S) instead of
-    O(B*H*S^2) -- this is the CPU/dry-run stand-in for the Pallas kernel
-    (same tiling idea, executed by XLA).
+    O(B*H*S^2) -- the stand-in for the Pallas kernel off the TPU and for
+    head dims that are not a multiple of 128 (same tiling idea, executed by
+    XLA).
     """
     B, S, H, Dh = q.shape
     Hkv = k.shape[2]
@@ -165,6 +171,45 @@ def _sdpa_chunked(
     # instead of stacking every chunk's probs (O(S^2) residuals otherwise)
     chunks = jax.lax.map(jax.checkpoint(one_chunk), jnp.arange(nq))
     return chunks.transpose(1, 0, 2, 3, 4).reshape(B, S, H, Dh)
+
+
+def _flash(
+    q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig, window: int | None
+) -> jax.Array:
+    """Causal attention through the Pallas flash kernel (differentiable).
+
+    XLA cannot partition a Mosaic kernel, so under a mesh the call runs in a
+    shard_map over the axes not yet manual, replicated over them.
+    """
+    attend = functools.partial(
+        flash_attention, causal=True, window=window,
+        softcap=cfg.attn_logit_softcap,
+    )
+    auto = _auto_axes()
+    if auto:
+        attend = jax.shard_map(
+            attend, in_specs=(P(),) * 3, out_specs=P(), axis_names=set(auto),
+            check_vma=False,
+        )
+    return attend(q, k, v)
+
+
+def _auto_axes() -> dict[str, int]:
+    """Size of each axis of the current mesh that is not manual."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return {
+        name: size
+        for name, size, kind in zip(mesh.axis_names, mesh.axis_sizes, mesh.axis_types)
+        if kind != AxisType.Manual
+    }
+
+
+def _unpartitioned() -> bool:
+    """Whether the step runs attention on one device as it stands: every
+    automatic mesh axis has size 1, or there is no mesh and one device."""
+    if jax.sharding.get_abstract_mesh().empty:
+        return jax.device_count() == 1
+    return all(size == 1 for size in _auto_axes().values())
 
 
 def _causal_mask(Sq: int, Sk: int, window: int | None) -> jax.Array:
@@ -207,14 +252,15 @@ def attention(
     with jax.named_scope("sdpa"):
         if cache is None:
             if impl == "pallas" and causal:
-                from repro.kernels.flash_attention import ops as fa_ops
-
-                out = fa_ops.flash_attention(
-                    q, k, v, causal=True, window=eff_window,
-                    softcap=cfg.attn_logit_softcap,
-                )
+                out = _flash(q, k, v, cfg, eff_window)
             elif causal and S > _CHUNK_THRESHOLD and S % _CHUNK_Q == 0:
-                out = _sdpa_chunked(q, k, v, cfg, eff_window)
+                # on a TPU the Pallas kernel, where the head dim fills MXU
+                # lanes and no mesh axis would have to partition the call
+                if (jax.default_backend() == "tpu" and dh % 128 == 0
+                        and _unpartitioned()):
+                    out = _flash(q, k, v, cfg, eff_window)
+                else:
+                    out = _sdpa_chunked(q, k, v, cfg, eff_window)
             else:
                 mask = _causal_mask(S, S, eff_window) if causal else None
                 out = _sdpa(q, k, v, mask, cfg)

@@ -15,6 +15,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import default_interpret
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
+from repro.kernels.flash_attention.ops import block_for
 from repro.kernels.gossip_mix import (
     gossip_mix,
     gossip_mix_ref,
@@ -87,7 +88,10 @@ def test_flash_attention_vs_ref(B, S, H, Hkv, D, window, softcap, dtype):
     q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype)
     k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), dtype)
     v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), dtype)
-    out = flash_attention(q, k, v, causal=True, window=window, softcap=softcap)
+    out = flash_attention(
+        q, k, v, causal=True, window=window, softcap=softcap,
+        block_q=128, block_kv=128,
+    )
     ref = flash_attention_ref(q, k, v, causal=True, window=window, softcap=softcap)
     tol = 2e-3 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(
@@ -109,7 +113,7 @@ def test_flash_attention_hypothesis(B, S, heads, D, seed):
     q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    out = flash_attention(q, k, v, causal=True)
+    out = flash_attention(q, k, v, causal=True, block_q=128, block_kv=128)
     ref = flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-3)
 
@@ -123,6 +127,80 @@ def test_flash_attention_small_seq_fallback():
     out = flash_attention(q, k, v)
     ref = flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_flash_attention_blocks_by_shape():
+    seqs = (16, 128, 300, 1024, 4096, 4608)
+    assert [block_for(s, 128) for s in seqs] == [128, 128, 384, 1024, 1024, 512]
+    assert [block_for(s, 256) for s in seqs] == [128, 128, 384, 512, 512, 512]
+    assert block_for(4096, 64) == 1024  # padded to a 128 head dim
+    rng = np.random.default_rng(1)
+    q = jnp.asarray(rng.normal(size=(1, 1536, 2, 128)), jnp.float32)
+    out = flash_attention(q, q, q)  # three 512 blocks
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(flash_attention_ref(q, q, q)), atol=2e-3, rtol=2e-3
+    )
+
+
+GRAD_CASES = [
+    # (S, H, Hkv, D, window, softcap)
+    (256, 2, 2, 128, None, 0.0),   # groups 1
+    (512, 4, 2, 128, None, 0.0),   # groups 2 (qwen3)
+    (256, 5, 1, 128, None, 0.0),   # groups 5 (the 14B cut)
+    (512, 2, 1, 128, 200, 0.0),    # window across blocks
+    (256, 2, 2, 128, None, 30.0),  # softcap
+    (256, 2, 1, 64, 100, 20.0),    # padded head dim, window and softcap
+]
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _attention_grads(attend, q, k, v, w):
+    """dq, dk, dv of sum(attend(q, k, v) * w)."""
+    loss = lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) * w)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("S,H,Hkv,D,window,softcap", GRAD_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad_vs_ref(S, H, Hkv, D, window, softcap, dtype):
+    rng = np.random.default_rng(S * H + D)
+    q = jnp.asarray(rng.normal(size=(1, S, H, D)), dtype)
+    k = jnp.asarray(rng.normal(size=(1, S, Hkv, D)), dtype)
+    v = jnp.asarray(rng.normal(size=(1, S, Hkv, D)), dtype)
+    w = jnp.asarray(rng.normal(size=(1, S, H, D)), jnp.float32)
+    opts = dict(causal=True, window=window, softcap=softcap)
+    got = _attention_grads(
+        lambda q, k, v: flash_attention(q, k, v, block_q=128, block_kv=128, **opts),
+        q, k, v, w,
+    )
+    want = _attention_grads(
+        lambda q, k, v: flash_attention_ref(q, k, v, **opts), q, k, v, w
+    )
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    for name, g, r in zip("qkv", got, want):
+        assert g.dtype == dtype and g.shape == r.shape, name
+        assert _rel_err(g, r) < tol, (name, _rel_err(g, r))
+
+
+def test_flash_attention_grad_under_vmap_over_nodes():
+    """The dsgd_pod step vmaps the loss over nodes: the kernel's forward and
+    backward batch along a leading node axis."""
+    rng = np.random.default_rng(7)
+    n, S, H, Hkv, D = 2, 256, 4, 2, 128
+    q = jnp.asarray(rng.normal(size=(n, 1, S, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(n, 1, S, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(n, 1, S, Hkv, D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(n, 1, S, H, D)), jnp.float32)
+    kernel = lambda q, k, v: flash_attention(q, k, v, block_q=128, block_kv=128)
+    got = jax.vmap(lambda *a: _attention_grads(kernel, *a))(q, k, v, w)
+    for node in range(n):
+        want = _attention_grads(flash_attention_ref, q[node], k[node], v[node], w[node])
+        for name, g, r in zip("qkv", got, want):
+            assert _rel_err(g[node], r) < 1e-5, (node, name)
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,8 @@ times. The topology is described inside a fixture, never at import, and
 all such compiles live in this one file (one process may hold libtpu).
 """
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -91,15 +93,27 @@ def test_gossip_mix_compiles(one_chip):
 
 
 def test_flash_attention_compiles_at_qwen3_shapes(one_chip):
+    """Forward and gradient at the qwen3 cell's shape: 10 x 4096 tokens,
+    16 q heads and 8 kv heads of 128, in the shape-chosen 1024 blocks."""
     cfg = get_config("qwen3-0.6b")
-    B, S, D = 1, 2048, cfg.head_dim
+    B, S, D = 10, 4096, cfg.head_dim
     q = _shape((B, S, cfg.num_heads, D), jnp.bfloat16, one_chip)
     kv = _shape((B, S, cfg.num_kv_heads, D), jnp.bfloat16, one_chip)
 
     def attend(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False)
 
-    _assert_kernel(jax.jit(attend).lower(q, kv, kv).compile())
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32))
+
+    forward = jax.jit(attend).lower(q, kv, kv).compile().as_text()
+    assert "flash_fwd" in forward and "flash_bwd" not in forward
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    for name in _FLASH_KERNELS:
+        assert name in grad.as_text(), name
+
+
+_FLASH_KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 
 
 def test_qwen3_dsgd_step_fits_one_chip(topo):
@@ -121,6 +135,42 @@ def test_qwen3_dsgd_step_fits_one_chip(topo):
     compiled = jax.jit(setup.train_step).lower(
         params, None, {"tokens": tokens, "labels": tokens}
     ).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
+
+
+def test_qwen3_dsgd_step_through_flash_kernel_fits_one_chip(topo, monkeypatch):
+    """The qwen3 cell's step, one node x 10 x 4096, with attention through
+    the Pallas kernel: what ``attention()`` selects on a TPU. The test steers
+    ``impl`` and interpret mode itself, since the compile runs on the CPU
+    backend. The XLA path's step takes 11.80 GiB (PERF.md)."""
+    fa_mod = importlib.import_module("repro.kernels.flash_attention.flash_attention")
+    monkeypatch.setattr(fa_mod, "resolve_interpret", lambda interpret: False)
+    jax.clear_caches()  # no trace that resolved interpret mode on the CPU
+    cfg = get_config("qwen3-0.6b")
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+        axis_types=(AxisType.Auto,) * 2,
+    )
+    schedule = schedule_from_result(learn_topology(
+        np.array([[0.9, 0.1 / 3, 0.1 / 3, 0.1 / 3]]), budget=2, lam=0.1))
+    setup = make_train_setup(cfg, mesh, mode="dsgd", schedule=schedule,
+                             lr=5e-3, impl="pallas")
+    params = jax.tree_util.tree_map(
+        lambda a, s: _shape(a.shape, a.dtype, NamedSharding(mesh, s)),
+        setup.abstract_params(), setup.param_specs,
+    )
+    tokens = _shape((1, 10, 4096), jnp.int32, NamedSharding(mesh, P("data")))
+    compiled = jax.jit(setup.train_step).lower(
+        params, None, {"tokens": tokens, "labels": tokens}
+    ).compile()
+    jax.clear_caches()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    for name in _FLASH_KERNELS:
+        assert name in text, name
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
