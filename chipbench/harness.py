@@ -9,7 +9,9 @@ compiled step back to back on the pool, from where set-up left it. Each
 step is timed on the host clock to the loss's arrival; nothing compiles
 inside (a compile there fails the run). After the window the device's
 peak memory is read, the program's state is freed, and the reference
-takes the same three steps from the same weights and batches.
+takes the same three steps from the same weights and batches. What
+belongs to the model (its weights, their paths in the program's tree,
+its reference layer and FLOPs) comes from the cell's family.
 """
 
 from __future__ import annotations
@@ -32,7 +34,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compare, flops, program, reduce, reference, spec, weights
+from . import compare, flops, program, reduce, reference, scopes, spec, weights
 from . import traffic as traffic_mod
 
 WARM_STEPS = 3  # set-up's steps: the comparison reads the first three
@@ -76,7 +78,12 @@ class RunRecord:
     tokens_per_step: int
     flops_per_step: float
     peak_flops: float | None
+    config: dict  # the cell's configuration file, as run
+    traffic: dict  # the cell's traffic mix
     trace: reduce.Trace | None = None
+    # {instruction: op_name} of the compiled step (``scopes.op_scopes``);
+    # traced runs only
+    op_scopes: dict[str, str] | None = None
 
 
 class Bench:
@@ -84,21 +91,22 @@ class Bench:
 
     def __init__(self, cell: spec.Cell, devices):
         self.cfg, self.traffic = cell.config, cell.traffic
-        self.prog = program.build(self.cfg, self.traffic)
+        fam = cell.family
+        self.prog = program.build(fam.model_config(self.cfg), self.traffic)
         self.lr = self.traffic["lr"]
         setup, cfg, n = self.prog.setup, self.cfg, self.traffic["nodes"]
-        names = sorted(weights.shapes(cfg))
+        paths = fam.paths(cfg)
 
         def init(key):
             return program.to_program(
-                program.broadcast_nodes(weights.init(key, cfg), n), setup)
+                program.broadcast_nodes(weights.init(key, cfg, fam), n), setup, paths)
 
         self.init = jax.jit(init, out_shardings=self.prog.param_shardings)
         pool = traffic_mod.make_pool_fn(self.traffic, cfg["vocab_size"])
         self.pool = jax.jit(lambda k: [program.place_batch(r) for r in pool(k)],
                             out_shardings=self.prog.batch_sharding)
         self.diff_norms = jax.jit(lambda a, b, scale: reference.diff_norms(
-            program.from_program(a, names), program.from_program(b, names), scale))
+            program.from_program(a, paths), program.from_program(b, paths), scale))
         self.step = None
 
     def start(self, seed: int):
@@ -159,7 +167,7 @@ class Reference:
 
     def __init__(self, cell: spec.Cell, devices, *, mm=reference.f32_mm,
                  half: bool = False, W: np.ndarray | None = None):
-        cfg, traffic = cell.config, cell.traffic
+        cfg, traffic, fam = cell.config, cell.traffic, cell.family
         n = traffic["nodes"]
         self.lr = traffic["lr"]
         mesh = jax.make_mesh((n,), ("node",), devices=list(devices)[:n],
@@ -168,9 +176,9 @@ class Reference:
         self.W = jnp.asarray(traffic["mixing_matrix"] if W is None else W,
                              jnp.float32)
         self.init = jax.jit(
-            lambda k: program.broadcast_nodes(weights.init(k, cfg), n),
+            lambda k: program.broadcast_nodes(weights.init(k, cfg, fam), n),
             out_shardings=self.by_node)
-        self.step = jax.jit(reference.make_step(cfg, self.lr, mm=mm, half=half),
+        self.step = jax.jit(reference.make_step(cfg, self.lr, fam, mm=mm, half=half),
                             out_shardings=(self.by_node, None, None))
         self.norms = jax.jit(reference.diff_norms)
 
@@ -218,7 +226,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     params, batches, prog_read = bench.start(seed)
     setup_s = time.perf_counter() - t_start
 
-    tr = None
+    tr = op_scopes = None
     if trace:
         out_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         try:
@@ -232,6 +240,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             finally:
                 jax.profiler.stop_trace()
             tr = reduce.load(next(Path(out_dir).rglob("*.xplane.pb")))
+            op_scopes = scopes.op_scopes(bench.step.as_text())
         finally:
             shutil.rmtree(out_dir, ignore_errors=True)
     else:
@@ -255,8 +264,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         chips=cell.chips, setup_s=setup_s, step_s=times,
         window_s=window_s,
         tokens_per_step=tp["nodes"] * tp["rows_per_node"] * tp["seq_len"],
-        flops_per_step=flops.step_flops(cell.config, tp),
-        peak_flops=peak, trace=tr)
+        flops_per_step=flops.step_flops(cell.config, tp, cell.family),
+        peak_flops=peak, config=cell.config, traffic=tp, trace=tr,
+        op_scopes=op_scopes)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = m.read(rec)
@@ -289,7 +299,13 @@ def main(argv, t_start: float, root: Path = spec.ROOT) -> int:
     cell = spec.load_cell(args.workload, root)
     from repro.launch.cache import enable_compile_cache
 
-    enable_compile_cache()
+    # a traced run reads the step's op_names, which only a cache entry
+    # keyed by the metadata too keeps; an untraced run keys by the
+    # instructions alone, as the program does
+    if args.trace:
+        scopes.enable_cache()
+    else:
+        enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     try:
         result = run(cell, args.seed, args.seconds, bool(args.trace), t_start)

@@ -1,10 +1,9 @@
 """Plain float32 reference of the D-SGD step, from the published equations.
 
-Qwen2/Qwen3 decoder (HF ``modeling_qwen2`` / ``modeling_qwen3``): RMSNorm
-(x * rsqrt(mean x^2 + eps) * w), grouped-query attention with optional
-QKV bias and per-head q/k RMSNorm before rotary embedding (rotate-half,
-theta from the config), causal softmax, SwiGLU MLP, final RMSNorm, LM
-head (the embedding, transposed, when tied), mean next-token cross
+The decoder layer is the model family's (``families/<family>.py``:
+``layer``, built from the helpers here: ``rms_norm``, ``rope``, causal
+``attention``). Around it: the token embedding, the final RMSNorm, the
+LM head (the embedding, transposed, when tied), mean next-token cross
 entropy. D-SGD (Algorithm 1 of the paper): every node takes one SGD step
 on its own rows, then theta_i <- sum_j W_ij theta_j.
 
@@ -97,30 +96,6 @@ def attention(q, k, v, mm, blk):
     return out.reshape(s, h, dh)
 
 
-def layer(x, p, cfg, mm):
-    """One decoder layer on one row. x: (S, D) float32; p: its weights."""
-    arch = cfg["implied_by_architecture"]
-    eps, hd = cfg["rms_norm_eps"], cfg["head_dim"]
-    s = x.shape[0]
-    h = rms_norm(x, p["ln1"], eps)
-    q, k, v = (mm("sd,df->sf", h, p[n]) for n in ("wq", "wk", "wv"))
-    if arch["qkv_bias"]:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(s, -1, hd)
-    k = k.reshape(s, -1, hd)
-    v = v.reshape(s, -1, hd)
-    if arch["qk_norm"]:
-        q = rms_norm(q, p["q_norm"], eps)
-        k = rms_norm(k, p["k_norm"], eps)
-    theta = float(cfg["rope_theta"])
-    a = attention(rope(q, theta), rope(k, theta), v, mm, min(BLOCK, s))
-    x = x + mm("sf,fd->sd", a.reshape(s, -1), p["wo"])
-    h = rms_norm(x, p["ln2"], eps)
-    g = mm("sd,df->sf", h, p["w_gate"])
-    u = mm("sd,df->sf", h, p["w_up"])
-    return x + mm("sf,fd->sd", jax.nn.silu(g) * u, p["w_down"])
-
-
 def head_loss(x, final_norm, head, labels, keep, cfg, mm):
     """Summed cross entropy of one row over the positions ``keep`` marks.
     x: (S, D) the last layer's output; head: (D, V)."""
@@ -139,8 +114,9 @@ def head_loss(x, final_norm, head, labels, keep, cfg, mm):
     return jnp.sum(jax.lax.map(jax.checkpoint(block), jnp.arange(x.shape[0] // blk)))
 
 
-def make_step(cfg: dict, lr: float, *, mm=f32_mm, half: bool = False):
-    """``step(theta, batch, W) -> (theta_next, mean loss, grad norms)``.
+def make_step(cfg: dict, lr: float, family, *, mm=f32_mm, half: bool = False):
+    """``step(theta, batch, W) -> (theta_next, mean loss, grad norms)`` of
+    ``family``'s model ``cfg``.
 
     theta: benchmark layout with a leading node axis, stored dtype.
     batch: {"tokens", "labels"} (nodes, rows, S). W: (n, n) float32.
@@ -172,15 +148,16 @@ def make_step(cfg: dict, lr: float, *, mm=f32_mm, half: bool = False):
             return {k: f32(jax.lax.dynamic_index_in_dim(theta[k], l, 1, False))
                     for k in names}
 
-        # a node's rows through one layer, one row at a time; all nodes
-        node_layer = lambda x, p: jax.lax.map(  # noqa: E731
-            jax.checkpoint(lambda r: layer(r, p, cfg, mm)), x)
-        all_layer = jax.vmap(node_layer)
+        # a node's rows through layer l, one row at a time; all nodes
+        def all_layer(x, p, l):
+            node_layer = lambda x, p: jax.lax.map(  # noqa: E731
+                jax.checkpoint(lambda r: family.layer(r, p, cfg, mm, l)), x)
+            return jax.vmap(node_layer)(x, p)
 
         x0 = jax.vmap(lambda e, t: f32(e)[t])(theta["embed"], tokens)
 
         def fwd(x, l):
-            return all_layer(x, weights_of(l)), x
+            return all_layer(x, weights_of(l), l), x
 
         x_last, inputs = jax.lax.scan(fwd, x0, jnp.arange(n_layers))
 
@@ -196,7 +173,7 @@ def make_step(cfg: dict, lr: float, *, mm=f32_mm, half: bool = False):
         def bwd(carry, l):
             dx, out = carry
             p = weights_of(l)
-            _, vjp = jax.vjp(all_layer, inputs[l], p)
+            _, vjp = jax.vjp(lambda x, p: all_layer(x, p, l), inputs[l], p)
             dx, dp = vjp(dx)
             out = {k: jax.lax.dynamic_update_index_in_dim(
                 out[k], mix(W, p[k] - lr * dp[k]).astype(dtype), l, 1) for k in names}

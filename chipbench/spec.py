@@ -6,6 +6,7 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable
 
 from . import compare
@@ -29,6 +30,7 @@ class Cell:
     config: dict
     traffic: dict
     limits: dict
+    family: ModuleType  # families/<family>.py, picked by the config's class
     end_to_end: tuple[Metric, ...]
     per_layer: tuple[Metric, ...]
 
@@ -38,15 +40,34 @@ def _load_json(path: Path) -> dict:
         return json.load(f)
 
 
-def load_reader(bench_dir: Path, name: str) -> Callable:
-    """``metrics/<name>.py``'s ``read`` function."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
+def _load_module(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
+        raise FileNotFoundError(f"no module {name!r} at {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench_dir: Path, name: str) -> Callable:
+    """``metrics/<name>.py``'s ``read`` function."""
+    return _load_module(bench_dir / "metrics" / f"{name}.py",
+                        f"chipbench_metric_{name}").read
+
+
+def load_family(config: dict, bench_dir: Path = ROOT / "chipbench") -> ModuleType:
+    """The module of ``families/*.py`` whose ``ARCHITECTURES`` holds the
+    configuration's published class, its ``architectures[0]``. A class
+    that no family claims, or that several do, raises."""
+    cls = config["architectures"][0]
+    families = [_load_module(path, f"chipbench_family_{path.stem}")
+                for path in sorted((bench_dir / "families").glob("*.py"))]
+    claims = [f for f in families if cls in f.ARCHITECTURES]
+    if len(claims) != 1:
+        known = sorted(c for f in families for c in f.ARCHITECTURES)
+        raise ValueError(f"{cls!r}: {len(claims)} families claim it, where one "
+                         f"must (families/*.py claim {known})")
+    return claims[0]
 
 
 def _metrics(bench_dir: Path, entries: list, workload: str) -> tuple[Metric, ...]:
@@ -109,6 +130,7 @@ def make_cell(name: str, chips: int, config_name: str, traffic_name: str,
         config=config,
         traffic=traffic,
         limits=load_limits(name, root),
+        family=load_family(config, bench_dir),
         end_to_end=_metrics(bench_dir, bench["end_to_end"], name),
         per_layer=_metrics(bench_dir, bench["per_layer"], name),
     )

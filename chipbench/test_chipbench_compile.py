@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from chipbench import program, reference, weights
+from chipbench import program, reference, spec
 
 HBM = 16 * 2**30
 BENCH = Path(__file__).resolve().parent
@@ -52,7 +52,7 @@ def _case_on(topo, case):
     n = mix["nodes"]
     mesh = Mesh(np.array(topo.devices[:n]).reshape(n, 1), ("data", "model"),
                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    return cfg, mix, mesh
+    return cfg, mix, mesh, spec.load_family(cfg)
 
 
 def _bytes(compiled):
@@ -62,10 +62,10 @@ def _bytes(compiled):
 
 @pytest.mark.parametrize("case", CASES, ids="-".join)
 def test_step_fits_and_holds_its_collectives(topo, case, monkeypatch):
-    cfg, tp, mesh = _case_on(topo, case)
+    cfg, tp, mesh, fam = _case_on(topo, case)
     # the program builds its mesh from jax.devices(); hand it the described one
     monkeypatch.setattr(program, "make_device_mesh", lambda d, m: mesh)
-    prog = program.build(cfg, tp)
+    prog = program.build(fam.model_config(cfg), tp)
     params = jax.tree_util.tree_map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         prog.setup.abstract_params(), prog.param_shardings)
@@ -80,16 +80,16 @@ def test_step_fits_and_holds_its_collectives(topo, case, monkeypatch):
 
 @pytest.mark.parametrize("case", CASES, ids="-".join)
 def test_reference_step_fits(topo, case):
-    cfg, tp, mesh = _case_on(topo, case)
+    cfg, tp, mesh, fam = _case_on(topo, case)
     n = tp["nodes"]
     by_node = NamedSharding(mesh, P("data"))
     theta = {k: jax.ShapeDtypeStruct((n,) + shape, jnp.dtype(cfg["torch_dtype"]),
                                      sharding=by_node)
-             for k, (shape, _) in weights.shapes(cfg).items()}
+             for k, (shape, _) in fam.shapes(cfg).items()}
     rows = jax.ShapeDtypeStruct((n, tp["rows_per_node"], tp["seq_len"]), jnp.int32,
                                 sharding=by_node)
     W = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=NamedSharding(mesh, P()))
-    step = reference.make_step(cfg, tp["lr"])
+    step = reference.make_step(cfg, tp["lr"], fam)
     compiled = jax.jit(step, out_shardings=(by_node, None, None)).lower(
         theta, {"tokens": rows, "labels": rows}, W).compile()
     assert _bytes(compiled) < HBM

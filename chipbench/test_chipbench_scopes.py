@@ -243,7 +243,7 @@ def _cut(seq_len):
 
 
 def _compile(cfg, mix):
-    prog = program.build(cfg, mix)
+    prog = program.build(spec.load_family(cfg).model_config(cfg), mix)
     params = jax.tree_util.tree_map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         prog.setup.abstract_params(), prog.param_shardings)

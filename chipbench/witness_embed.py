@@ -33,7 +33,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from chipbench import harness, program, spec, weights  # noqa: E402
+from chipbench import harness, spec, weights  # noqa: E402
 
 
 @contextlib.contextmanager
@@ -67,7 +67,7 @@ def main(argv) -> int:
     enable_compile_cache()
     cell = spec.load_cell(args.workload)
     devices = harness.check_devices(cell.chips)[: cell.chips]
-    mcfg = program.model_config(cell.config)
+    mcfg = cell.family.model_config(cell.config)
     lr = cell.traffic["lr"]
     bench = harness.Bench(cell, devices)
     ref = harness.Reference(cell, devices)
